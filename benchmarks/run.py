@@ -110,6 +110,8 @@ def main(argv=None):
                          "file (CI uploads it as the bench-smoke "
                          "artifact); never touches results/bench")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.check:
         if args.quick:

@@ -48,6 +48,7 @@ kept as the historical entry points.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -62,6 +63,29 @@ from repro.nn.act import fast_sigmoid, uniform_from_bits
 class IALSState(NamedTuple):
     ls_state: object      # LS state; (B, ...) leaves, (B, A, ...) if multi
     aip_state: jax.Array  # (B, [A,] H) GRU hidden / (B, [A,] stack, d_in)
+
+
+def kernel_fns(local_env: BatchedLocalEnv, ls_def, ls_dtypes, nz_def,
+               nz_dtypes):
+    """-> (tick, dset, obs): the LS's ``rollout_tick`` / ``dset_fn`` /
+    ``obs_fn`` on the encoded leaf tuples the rollout kernels carry
+    (``envs.api.kernel_codec``; ``*_def`` / ``*_dtypes`` describe the
+    flattened LS state and noise pytrees) — what the kernels and their
+    ``kernels/ref.py`` oracles trace per tick."""
+    ls_enc, ls_dec = kernel_codec(ls_def, ls_dtypes)
+    _, nz_dec = kernel_codec(nz_def, nz_dtypes)
+
+    def tick(vals, a, u, nzv):
+        st2, r = local_env.rollout_tick(ls_dec(vals), a, u, nz_dec(nzv))
+        return ls_enc(jax.tree_util.tree_leaves(st2)), r
+
+    def dset(vals, a):
+        return local_env.dset_fn(ls_dec(vals), a)
+
+    def obs(vals):
+        return local_env.obs_fn(ls_dec(vals))
+
+    return tick, dset, obs
 
 
 def _check_stateless(stateless, fixed_marginal, fixed_marginal_vec):
@@ -110,7 +134,10 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
     is pinned to the IALS rules of ``distributed/sharding.py`` (env lanes
     over the data axes, the agent axis over "model" when it divides) via
     ``with_sharding_constraint``, and GSPMD propagates through the
-    horizon. ``reset`` stays unconstrained on purpose: constraining its
+    horizon. The kernel route of ``rollout`` / ``policy_rollout`` runs
+    under ``shard_map`` on the same rules instead, each device on its own
+    lanes (GSPMD cannot split a Pallas call; it would gather the lanes
+    around it). ``reset`` stays unconstrained on purpose: constraining its
     output back-propagates the sharding into the threefry RNG lowering
     and changes the drawn bits — shard fresh states eagerly with
     ``sharding.shard_ials_state`` instead. ``mesh=None`` (or a size-1
@@ -226,7 +253,9 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
     # --- whole-horizon path -------------------------------------------
     # agent-major lane layout at the kernel boundary: lane a*B + b, so
     # each kernel lane block belongs to one agent and indexes that
-    # agent's stacked weights (no-ops when A == 1)
+    # agent's stacked weights (no-ops when A == 1). The agent count is
+    # read off the shapes: under a mesh these run per device, on that
+    # device's share of the agents.
     def _lane_fold(x):                    # (B, A, ...) -> (A·B, ...)
         if not multi:
             return x
@@ -235,7 +264,7 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
     def _lane_unfold(x, B):               # (A·B, ...) -> (B, A, ...)
         if not multi:
             return x
-        return x.reshape((A, B) + x.shape[1:]).swapaxes(0, 1)
+        return x.reshape((-1, B) + x.shape[1:]).swapaxes(0, 1)
 
     def _stream_fold(x):                  # (T, B, A, ...) -> (T, A·B, ...)
         if not multi:
@@ -245,12 +274,32 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
     def _stream_unfold(x, B):             # (T, A·B, ...) -> (T, B, A, ...)
         if not multi:
             return x
-        return x.reshape((x.shape[0], A, B) + x.shape[2:]).swapaxes(1, 2)
+        return x.reshape((x.shape[0], -1, B) + x.shape[2:]).swapaxes(1, 2)
 
-    def _noise_fold(x, B):   # (T, B·A, ...) batch-major -> (T, A·B, ...)
+    def _env_noise(noise, B):
+        """The LS noise leaves are (T, B·A, ...) batch-major; split the
+        agent axis out so they shard and fold like every other stream."""
         if not multi:
-            return x
-        return _stream_fold(x.reshape((x.shape[0], B, A) + x.shape[2:]))
+            return noise
+        return {"bits": noise["bits"], "env": tmap(
+            lambda l: l.reshape((l.shape[0], B, A) + l.shape[2:]),
+            noise["env"])}
+
+    def _lane_parallel(fn, B, in_kinds, out_kinds, *args):
+        """Run the kernel route ``fn`` on each device's own env lanes. A
+        Pallas call is a custom call that GSPMD cannot partition, so on
+        a mesh the route is a ``shard_map`` under the IALS rules of
+        ``distributed/sharding.py`` (``ials_kernel_specs`` names each
+        operand kind) and ``fn`` sees the local (B/n, ...) leaves. No
+        lane crosses a device, so the result is the single-device one."""
+        if mesh is None:
+            return fn(*args)
+        from repro.distributed import sharding as _shd
+        spec = _shd.ials_kernel_specs(B, A, mesh)
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=tuple(spec[k] for k in in_kinds),
+            out_specs=tuple(spec[k] for k in out_kinds),
+            check_vma=False)(*args)
 
     _kernel_fns = {}      # structural key -> stable (tick, dset, obs)
     #                       closures (stable identity keeps the kernel's
@@ -259,27 +308,58 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
     def _kernel_closures(ls_def, ls_dtypes, nz_def, nz_dtypes):
         key_ = (ls_def, ls_dtypes, nz_def, nz_dtypes)
         if key_ not in _kernel_fns:
-            ls_enc, ls_dec = kernel_codec(ls_def, ls_dtypes)
-            _, nz_dec = kernel_codec(nz_def, nz_dtypes)
-
-            def k_dset(vals, a):
-                return local_env.dset_fn(ls_dec(vals), a)
-
-            def k_tick(vals, a, u, nzv):
-                st2, r = local_env.rollout_tick(ls_dec(vals), a, u,
-                                                nz_dec(nzv))
-                return ls_enc(jax.tree_util.tree_leaves(st2)), r
-
-            def k_obs(vals):
-                return local_env.obs_fn(ls_dec(vals))
-
-            _kernel_fns[key_] = (k_tick, k_dset, k_obs)
+            _kernel_fns[key_] = kernel_fns(local_env, *key_)
         return _kernel_fns[key_]
 
     def _stacked(tree):
         """aip_params with a leading (A,) axis on every leaf (the A=1
         squeeze stacks on the fly)."""
         return tree if multi else tmap(lambda l: l[None], tree)
+
+    def _kernel_operands(state, env_noise):
+        """Lane-fold, flatten and encode the LS state and noise leaves
+        -> (encoded ls leaves, encoded noise leaves, ls encode, ls
+        decode, tick, dset, obs)."""
+        ls_leaves, ls_def = jax.tree_util.tree_flatten(
+            tmap(_lane_fold, state.ls_state))
+        nz_leaves, nz_def = jax.tree_util.tree_flatten(
+            tmap(_stream_fold, env_noise))
+        ls_dtypes = tuple(l.dtype for l in ls_leaves)
+        nz_dtypes = tuple(l.dtype for l in nz_leaves)
+        fns = _kernel_closures(ls_def, ls_dtypes, nz_def, nz_dtypes)
+        ls_enc, ls_dec = kernel_codec(ls_def, ls_dtypes)
+        nz_enc, _ = kernel_codec(nz_def, nz_dtypes)
+        return (ls_enc(ls_leaves), nz_enc(nz_leaves), ls_enc, ls_dec) + fns
+
+    def _kernel_rollout(state: IALSState, actions, noise, p):
+        from repro.kernels import ops  # deferred: keeps kernels
+        #                                optional for the scan path
+        B = _batch(state)
+        ls, nz, _, ls_dec, k_tick, k_dset, _ = _kernel_operands(
+            state, noise["env"])
+        acts = _stream_fold(actions)                   # (T, A·B)
+        bits = _stream_fold(noise["bits"])             # (T, A·B, M)
+        n_ag = acts.shape[1] // B
+        if aip_cfg.kind == "gru":
+            g, hd = p["gru"], p["head"]
+            final, sT, rews = ops.ials_rollout_multi(
+                ls, _lane_fold(state.aip_state), g["wx"], g["wh"], g["b"],
+                hd["w"], hd["b"], acts, bits, nz, n_agents=n_ag,
+                tick_fn=k_tick, dset_fn=k_dset)
+            aip_T = _lane_unfold(sT, B)
+        else:
+            buf0 = _lane_fold(state.aip_state)         # (L, stack, d_in)
+            L = buf0.shape[0]
+            final, sT, rews = ops.fnn_rollout(
+                ls, buf0.reshape(L, -1), p["l1"]["w"], p["l1"]["b"],
+                p["l2"]["w"], p["l2"]["b"], p["head"]["w"],
+                p["head"]["b"], acts, bits, nz, n_agents=n_ag,
+                tick_fn=k_tick, dset_fn=k_dset)
+            aip_T = _lane_unfold(
+                sT.reshape(L, aip_cfg.stack, aip_cfg.d_in), B)
+        ls_T = tmap(lambda l: _lane_unfold(l, B), ls_dec(final))
+        return (IALSState(ls_state=ls_T, aip_state=aip_T),
+                _stream_unfold(rews, B))
 
     def rollout(state: IALSState, actions, keys):
         """(state, actions (T, B[, A]), keys (T,)) -> (state, rewards
@@ -295,43 +375,11 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
                            is not None
                            else jax.default_backend() == "tpu"))
         if use_kernel:
-            from repro.kernels import ops  # deferred: keeps kernels
-            #                                optional for the scan path
-            ls_leaves, ls_def = jax.tree_util.tree_flatten(
-                tmap(_lane_fold, state.ls_state))
-            nz_leaves, nz_def = jax.tree_util.tree_flatten(
-                tmap(lambda l: _noise_fold(l, B), noise["env"]))
-            ls_dtypes = tuple(l.dtype for l in ls_leaves)
-            nz_dtypes = tuple(l.dtype for l in nz_leaves)
-            k_tick, k_dset, _ = _kernel_closures(ls_def, ls_dtypes,
-                                                 nz_def, nz_dtypes)
-            ls_enc, ls_dec = kernel_codec(ls_def, ls_dtypes)
-            nz_enc, _ = kernel_codec(nz_def, nz_dtypes)
-            acts = _stream_fold(actions)               # (T, A·B)
-            bits = _stream_fold(noise["bits"])         # (T, A·B, M)
-            p = _stacked(aip_params)
-            if aip_cfg.kind == "gru":
-                g, hd = p["gru"], p["head"]
-                final, sT, rews = ops.ials_rollout_multi(
-                    ls_enc(ls_leaves), _lane_fold(state.aip_state),
-                    g["wx"], g["wh"], g["b"], hd["w"], hd["b"], acts,
-                    bits, nz_enc(nz_leaves), n_agents=A, tick_fn=k_tick,
-                    dset_fn=k_dset)
-                aip_T = _lane_unfold(sT, B)
-            else:
-                buf0 = _lane_fold(state.aip_state)     # (L, stack, d_in)
-                L = buf0.shape[0]
-                buf0 = buf0.reshape(L, -1)
-                final, sT, rews = ops.fnn_rollout(
-                    ls_enc(ls_leaves), buf0, p["l1"]["w"], p["l1"]["b"],
-                    p["l2"]["w"], p["l2"]["b"], p["head"]["w"],
-                    p["head"]["b"], acts, bits, nz_enc(nz_leaves),
-                    n_agents=A, tick_fn=k_tick, dset_fn=k_dset)
-                aip_T = _lane_unfold(
-                    sT.reshape(L, aip_cfg.stack, aip_cfg.d_in), B)
-            ls_T = tmap(lambda l: _lane_unfold(l, B), ls_dec(final))
-            return (_constrain(IALSState(ls_state=ls_T, aip_state=aip_T)),
-                    _stream_unfold(rews, B))
+            state, rews = _lane_parallel(
+                _kernel_rollout, B, ("state", "stream", "stream", "agent"),
+                ("state", "stream"), state, actions, _env_noise(noise, B),
+                _stacked(aip_params))
+            return _constrain(state), rews
 
         def tick(carry, xs):
             a, n = xs
@@ -353,35 +401,15 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
                          is not None
                          else jax.default_backend() == "tpu"))
 
-    def policy_rollout(state: IALSState, frames, t_in_ep, pol_params,
-                       gumbel, noise, reset_states, *, episode_len: int,
-                       fast_gates: bool):
-        """``BatchedEnv.policy_rollout`` (see envs/api.py): T PPO acting
-        ticks — policy forward, Gumbel-argmax actions, AIP sample, LS
-        tick, reward, periodic resets — as ONE ``kernels.ops`` dispatch
-        (the Pallas kernel on TPU, the identical-math oracle scan
-        elsewhere). All randomness arrives pre-drawn: ``gumbel``
-        (T, B, [A,] n_actions), ``noise`` = ``horizon_noise`` of this
-        engine's ``noise_fn``, ``reset_states`` = T-stacked ``reset``
-        results. The episode-reset schedule is closed-form from
-        ``t_in_ep`` (invariant: 0 <= t_in_ep < episode_len, which PPO's
-        reset logic maintains); resets restore the streamed LS leaves
-        and re-zero the AIP state (its init value)."""
+    def _kernel_policy_rollout(state: IALSState, frames, t_in_ep, gumbel,
+                               noise, reset_states, p, pol_params, *,
+                               episode_len: int, fast_gates: bool):
         from repro.kernels import ops  # deferred: keeps kernels optional
-        state = _constrain(state)
         B = _batch(state)
         T = gumbel.shape[0]
-        ls_leaves, ls_def = jax.tree_util.tree_flatten(
-            tmap(_lane_fold, state.ls_state))
-        nz_leaves, nz_def = jax.tree_util.tree_flatten(
-            tmap(lambda l: _noise_fold(l, B), noise["env"]))
-        ls_dtypes = tuple(l.dtype for l in ls_leaves)
-        nz_dtypes = tuple(l.dtype for l in nz_leaves)
-        k_tick, k_dset, k_obs = _kernel_closures(ls_def, ls_dtypes,
-                                                 nz_def, nz_dtypes)
-        ls_enc, ls_dec = kernel_codec(ls_def, ls_dtypes)
-        nz_enc, _ = kernel_codec(nz_def, nz_dtypes)
-        rls_leaves, _ = jax.tree_util.tree_flatten(
+        ls, nz, ls_enc, ls_dec, k_tick, k_dset, k_obs = _kernel_operands(
+            state, noise["env"])
+        rls_leaves = jax.tree_util.tree_leaves(
             tmap(_stream_fold, reset_states.ls_state))
 
         # the deterministic reset schedule: tick i is done iff the
@@ -391,13 +419,12 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
                  + jnp.arange(T, dtype=jnp.int32)[:, None])
         done_env = (ticks % episode_len) == 0            # (T, B)
         t_out = (t_in_ep + T) % episode_len
-        done_lanes = done_env.astype(jnp.int32)
-        if multi:                       # lane a*B + b <-> env b
-            done_lanes = jnp.tile(done_lanes, (1, A))
-
         frames_l = _lane_fold(frames)                    # (L, k, d)
-        stack, d_obs = frames_l.shape[-2], frames_l.shape[-1]
-        p = _stacked(aip_params)
+        L, stack, d_obs = frames_l.shape
+        n_ag = L // B
+        # lane a*B + b <-> env b
+        done_lanes = jnp.tile(done_env.astype(jnp.int32), (1, n_ag))
+
         if aip_cfg.kind == "gru":
             aw = (p["gru"]["wx"], p["gru"]["wh"], p["gru"]["b"],
                   p["head"]["w"], p["head"]["b"])
@@ -405,17 +432,15 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
         else:
             aw = (p["l1"]["w"], p["l1"]["b"], p["l2"]["w"],
                   p["l2"]["b"], p["head"]["w"], p["head"]["b"])
-            buf = _lane_fold(state.aip_state)
-            s0 = buf.reshape(buf.shape[0], -1)
+            s0 = _lane_fold(state.aip_state).reshape(L, -1)
         from repro.rl.ppo import flat_policy_weights  # deferred: no cycle
-        pw = flat_policy_weights(pol_params)
         fin_ls, sT, fT, x, a, logits, v, r = ops.policy_rollout(
-            ls_enc(ls_leaves), s0,
-            frames_l.reshape(frames_l.shape[0], -1), aw, pw,
-            _stream_fold(gumbel), _stream_fold(noise["bits"]),
-            done_lanes, nz_enc(nz_leaves), ls_enc(rls_leaves),
-            kind=aip_cfg.kind, n_agents=A, fast_gates=fast_gates,
-            tick_fn=k_tick, dset_fn=k_dset, obs_fn=k_obs)
+            ls, s0, frames_l.reshape(L, -1), aw,
+            flat_policy_weights(pol_params), _stream_fold(gumbel),
+            _stream_fold(noise["bits"]), done_lanes, nz,
+            ls_enc(rls_leaves), kind=aip_cfg.kind, n_agents=n_ag,
+            fast_gates=fast_gates, tick_fn=k_tick, dset_fn=k_dset,
+            obs_fn=k_obs)
         ls_T = tmap(lambda l: _lane_unfold(l, B), ls_dec(fin_ls))
         if aip_cfg.kind == "gru":
             aip_T = _lane_unfold(sT, B)
@@ -431,8 +456,36 @@ def make_unified_ials(local_env: BatchedLocalEnv, aip_params,
         out = {"x": _stream_unfold(x, B), "a": _stream_unfold(a, B),
                "logits": _stream_unfold(logits, B),
                "v": _stream_unfold(v, B), "r": r_u, "done": done_b}
-        return (_constrain(IALSState(ls_state=ls_T, aip_state=aip_T)),
-                frames_T, t_out, out)
+        return (IALSState(ls_state=ls_T, aip_state=aip_T), frames_T,
+                t_out, out)
+
+    def policy_rollout(state: IALSState, frames, t_in_ep, pol_params,
+                       gumbel, noise, reset_states, *, episode_len: int,
+                       fast_gates: bool):
+        """``BatchedEnv.policy_rollout`` (see envs/api.py): T PPO acting
+        ticks — policy forward, Gumbel-argmax actions, AIP sample, LS
+        tick, reward, periodic resets — as ONE ``kernels.ops`` dispatch
+        (the Pallas kernel on TPU, the identical-math oracle scan
+        elsewhere; per device on a mesh). All randomness arrives
+        pre-drawn: ``gumbel`` (T, B, [A,] n_actions), ``noise`` =
+        ``horizon_noise`` of this engine's ``noise_fn``,
+        ``reset_states`` = T-stacked ``reset`` results. The episode-reset
+        schedule is closed-form from ``t_in_ep`` (invariant: 0 <= t_in_ep
+        < episode_len, which PPO's reset logic maintains); resets restore
+        the streamed LS leaves and re-zero the AIP state (its init
+        value)."""
+        state = _constrain(state)
+        B = _batch(state)
+        body = functools.partial(_kernel_policy_rollout,
+                                 episode_len=episode_len,
+                                 fast_gates=fast_gates)
+        state, frames_T, t_out, out = _lane_parallel(
+            body, B, ("state", "state", "lanes", "stream", "stream",
+                      "stream", "agent", "rep"),
+            ("state", "state", "lanes", "stream"), state, frames, t_in_ep,
+            gumbel, _env_noise(noise, B), reset_states,
+            _stacked(aip_params), pol_params)
+        return _constrain(state), frames_T, t_out, out
 
     def observe(state: IALSState):
         B = _batch(state)

@@ -405,6 +405,22 @@ def ials_aip_param_specs(params, mesh: Mesh, n_agents: int = 1,
     return jax.tree_util.tree_map(spec, params)
 
 
+def ials_kernel_specs(batch: int, n_agents: int, mesh: Mesh) -> Dict:
+    """The PartitionSpec of each operand kind of the engine's
+    lane-parallel kernel route (the ``shard_map`` in core/engine.py),
+    each a prefix for a whole subtree: "state" (B, [A,] ...) leaves,
+    "stream" (T, B, [A,] ...) leaves, "lanes" per-env (B,) vectors,
+    "agent" stacked (A, ...) AIP weights, "rep" replicated. Same lane
+    and agent axes as ``ials_state_pspec``."""
+    lane, agent_ax = ials_lane_axes(batch, n_agents, mesh)
+    ag = [agent_ax] if n_agents > 1 else []
+    return {"state": _pspec([_lead(lane)] + ag, 1 + len(ag)),
+            "stream": _pspec([None, _lead(lane)] + ag, 2 + len(ag)),
+            "lanes": _pspec([_lead(lane)], 1),
+            "agent": _pspec([agent_ax], 1) if n_agents > 1 else P(),
+            "rep": P()}
+
+
 def ials_replicated_specs(params):
     """PPO policy / optimizer params: replicated everywhere (pure DP)."""
     return jax.tree_util.tree_map(lambda _: P(), params)

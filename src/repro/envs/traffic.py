@@ -82,20 +82,27 @@ def _advance_lane(occ, can_cross):
     is occupied and the stop-line car crosses. The suffix-OR is log2(L)
     rounds of shift-and-or on the boolean lane — O(log L) fused ops
     instead of an L-stage dependent chain, which matters because this runs
-    per tick in every simulator's hot loop (GS and LS alike)."""
+    per tick in every simulator's hot loop (GS and LS alike).
+
+    The algebra runs on int32 0/1 cells with shifts written as
+    slice-and-concat (no scatter), because the LS tick that calls it is
+    traced into the Pallas rollout kernels (ARCHITECTURE §2); outputs
+    come back in ``occ``'s dtype, value-identical to the bool form."""
     L = occ.shape[-1]
-    g = ~occ                                  # suffix-OR of free cells
+    o = occ.astype(jnp.int32)
+    zero = jnp.zeros_like(o[..., :1])
+    g = 1 - o                                 # suffix-OR of free cells
     s = 1
     while s < L:
-        g = g.at[..., :L - s].set(g[..., :L - s] | g[..., s:])
+        g = g | jnp.concatenate([g[..., s:], jnp.zeros_like(g[..., :s])],
+                                axis=-1)
         s *= 2
-    gap = jnp.concatenate(                    # a free cell strictly ahead
-        [g[..., 1:], jnp.zeros_like(g[..., :1])], axis=-1)
-    moved = occ & (gap | can_cross[..., None])
-    stay = occ & ~moved
-    shifted = jnp.concatenate(
-        [jnp.zeros_like(occ[..., :1]), moved[..., :-1]], axis=-1)
-    return stay | shifted, moved, moved[..., -1]
+    gap = jnp.concatenate([g[..., 1:], zero], axis=-1)   # free cell ahead
+    moved = o & (gap | can_cross.astype(jnp.int32)[..., None])
+    stay = o & (1 - moved)
+    shifted = jnp.concatenate([zero, moved[..., :-1]], axis=-1)
+    return ((stay | shifted).astype(occ.dtype), moved.astype(occ.dtype),
+            moved[..., L - 1].astype(occ.dtype))
 
 
 # directions: 0 south(+i), 1 north(-i), 2 west(-j), 3 east(+j)
@@ -447,11 +454,15 @@ def make_batched_local_traffic_env(
     spec = EnvSpec(name="traffic-ls-b", obs_dim=4 * L + 1, n_actions=2,
                    n_influence=M, dset_dim=4 * L, dset_full_dim=4 * L + 1)
 
+    # observe / rollout_tick / dset_fn are traced into the Pallas rollout
+    # kernels: casts come before every reshape, slice, or concat (Mosaic
+    # cannot reshape bool or compare int8 vectors), the lane algebra is
+    # int32 0/1, and reductions run one axis at a time (ARCHITECTURE §2)
     def observe(state: LocalTrafficState):
         B = state.lanes.shape[0]
         return jnp.concatenate(
-            [state.lanes.reshape(B, -1).astype(jnp.float32),
-             state.phase[:, None].astype(jnp.float32)], axis=-1)
+            [state.lanes.astype(jnp.float32).reshape(B, -1),
+             state.phase.astype(jnp.float32)[:, None]], axis=-1)
 
     def reset(key, n_envs: int):
         lanes = jax.random.bernoulli(key, 0.15, (n_envs, 4, L))
@@ -463,22 +474,23 @@ def make_batched_local_traffic_env(
 
     def rollout_tick(state: LocalTrafficState, actions, u, noise):
         del noise
-        lanes = state.lanes                              # (B, 4, L)
-        phase = actions.astype(jnp.int8)                 # (B,)
-        ns = (phase == 0)[:, None]
-        green = jnp.concatenate([ns, ns, ~ns, ~ns], axis=-1)   # (B, 4)
-        can_cross = green
+        occ = state.lanes.astype(jnp.int32)              # (B, 4, L)
+        ns = (actions == 0).astype(jnp.int32)[:, None]   # phase 0 = NS
+        green = jnp.concatenate([ns, ns, 1 - ns, 1 - ns], axis=-1)
+        can_cross = green                                # (B, 4)
         if cfg.ext_influence:
-            can_cross = green & ~u[:, 4:].astype(bool)
-        new_lanes, moved, _ = _advance_lane(lanes, can_cross)
-        inj = u[:, :4].astype(bool) & ~new_lanes[:, :, 0]
-        new_lanes = new_lanes.at[:, :, 0].set(new_lanes[:, :, 0] | inj)
+            can_cross = green & (u[:, 4:] == 0).astype(jnp.int32)
+        new, moved, _ = _advance_lane(occ, can_cross)
+        inj = (u[:, :4] != 0).astype(jnp.int32) & (1 - new[:, :, 0])
+        new = new | jnp.concatenate(
+            [inj[:, :, None], jnp.zeros_like(new[:, :, 1:])], axis=-1)
 
-        n_cars = lanes.sum(axis=(1, 2))
-        n_moved = moved.sum(axis=(1, 2))
+        n_cars = occ.sum(-1).sum(-1)
+        n_moved = moved.sum(-1).sum(-1)
         reward = jnp.where(n_cars > 0,
                            n_moved / jnp.maximum(n_cars, 1), 1.0)
-        return LocalTrafficState(lanes=new_lanes, phase=phase), reward
+        return LocalTrafficState(lanes=new.astype(state.lanes.dtype),
+                                 phase=actions.astype(jnp.int8)), reward
 
     def step_det(state: LocalTrafficState, actions, u, noise):
         new_state, reward = rollout_tick(state, actions, u, noise)
@@ -498,13 +510,13 @@ def make_batched_local_traffic_env(
 
     def dset_fn(state: LocalTrafficState, actions):
         B = state.lanes.shape[0]
-        return state.lanes.reshape(B, -1).astype(jnp.float32)
+        return state.lanes.astype(jnp.float32).reshape(B, -1)
 
     return BatchedLocalEnv(spec=spec, reset=reset, step=step,
                            observe=observe, dset_fn=dset_fn,
                            noise_fn=noise_fn, step_det=step_det,
                            rollout_tick=rollout_tick,
-                           # reshape + astype + concat only: already
+                           # astype + reshape + concat only: already
                            # kernel-safe, so the policy-rollout kernel
                            # traces the real observe
                            obs_fn=observe)

@@ -12,13 +12,13 @@ caller-supplied counter-based random bits, with every intermediate
 resident in VMEM.
 
 The rollout kernels go one level up (the Large-Batch-Simulation move,
-Shacklett et al. 2021): ONE generalized grid, ``(A·B-blocks, T)`` — lane
-blocks on the parallel outer axis, the horizon on an inner "arbitrary"
-axis like ``gru.py`` — with the AIP recurrent state AND the local
-simulator's state leaves resident in VMEM scratch across all T grid
+Shacklett et al. 2021): ONE generalized grid, ``(A, B-blocks, T)`` —
+agents and lane blocks on parallel axes, the horizon on an inner
+"arbitrary" axis like ``gru.py`` — with the AIP recurrent state AND the
+local simulator's state leaves resident in VMEM scratch across all T grid
 steps. Lanes are laid out *agent-major* (lane ``a*B + b``), so every lane
 block belongs to exactly one agent and the per-agent weights are just
-another blocked input indexed by ``block_index // (B / block_b)``; the
+another blocked input indexed by the grid's agent coordinate; the
 agent axis is a grid dimension, not a Python-level engine variant. The
 caller supplies the LS transition (``tick_fn``) and d-set extraction
 (``dset_fn``) as pure jnp functions that get traced straight into the
@@ -66,8 +66,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.nn.act import fast_sigmoid, fast_tanh, uniform_from_bits
+
+
+def _shift_in(buf, x):
+    """Frame-stack shift: drop the oldest (B, d) frame of the flattened
+    (B, stack·d) buffer and append ``x`` as the newest. A one-frame stack
+    is ``x`` itself (Mosaic has no zero-width vectors)."""
+    if buf.shape[1] == x.shape[1]:
+        return x
+    return jnp.concatenate([buf[:, x.shape[1]:], x], axis=1)
 
 
 def _gru_cell(w, h, d, bits, *, H: int):
@@ -100,7 +108,7 @@ def _fnn_cell(w, buf, d, bits):
     newest frame last), matching ``influence.step``'s returned buffer.
     """
     w1, b1, w2, b2, hw, hb = (v.astype(jnp.float32) for v in w)
-    buf2 = jnp.concatenate([buf[:, d.shape[1]:], d], axis=1)
+    buf2 = _shift_in(buf, d)
     h = jax.nn.relu(
         jax.lax.dot_general(buf2, w1, (((1,), (0,)), ((), ()))) + b1)
     h = jax.nn.relu(
@@ -130,9 +138,12 @@ def _policy_cell(w, x, *, fast_gates: bool):
     # a dispatch-bound micro-GEMM; fusing pins the reduction order shared
     # with the oracle and feeds the MXU one op instead of two
     hw = jnp.concatenate([piw, vw], axis=1)
-    hb = jnp.concatenate([pib, vb], axis=0)
+    hb = jnp.concatenate([pib, vb], axis=-1)
     out = jax.lax.dot_general(h, hw, (((1,), (0,)), ((), ()))) + hb
-    return out[:, :-1], out[:, -1]
+    # static slices only: an integer column index lowers to a
+    # dynamic_slice, which Mosaic cannot compile
+    n_act = piw.shape[1]
+    return out[:, :n_act], out[:, n_act:].reshape(out.shape[0])
 
 
 def _aip_step_kernel(d_ref, h_ref, wx_ref, wh_ref, b_ref, hw_ref, hb_ref,
@@ -182,7 +193,7 @@ def aip_step(d, h, wx, wh, b, hw, hb, bits, *, interpret: bool | None = None):
             jax.ShapeDtypeStruct((B, M), jnp.float32),
             jax.ShapeDtypeStruct((B, M), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(),
+        compiler_params=pltpu.CompilerParams(),
         interpret=interpret,
     )(d, h, wx, wh, b, hw, hb, bits)
     return h2, logits, u
@@ -195,9 +206,11 @@ def _serve_forward_kernel(f_ref, m_ref, w1_ref, b1_ref, w2_ref, b2_ref,
     w = (w1_ref[...], b1_ref[...], w2_ref[...], b2_ref[...],
          piw_ref[...], pib_ref[...], vw_ref[...], vb_ref[...])
     logits, v = _policy_cell(w, x, fast_gates=fast_gates)
-    m = m_ref[...] != 0                                # (bs,)
-    lg_ref[...] = jnp.where(m[:, None], logits, 0.0).astype(lg_ref.dtype)
-    v_ref[...] = jnp.where(m, v, 0.0).astype(v_ref.dtype)
+    m = m_ref[...]                                     # (bs,) int32
+    # expand the int32 mask, then compare: Mosaic cannot reshape bools
+    lg_ref[...] = jnp.where(m[:, None] != 0, logits,
+                            0.0).astype(lg_ref.dtype)
+    v_ref[...] = jnp.where(m != 0, v, 0.0).astype(v_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -255,7 +268,7 @@ def serve_forward(frames, mask, pol_w, *, fast_gates: bool,
             jax.ShapeDtypeStruct((S, n_act), jnp.float32),
             jax.ShapeDtypeStruct((S,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(),
+        compiler_params=pltpu.CompilerParams(),
         interpret=interpret,
     )(frames, mask, w1, b1, w2, b2, piw, pib, vw, vb)
     return logits, v
@@ -278,12 +291,11 @@ def _serve_forward_multi_kernel(f_ref, m_ref, p_ref, w1_ref, b1_ref,
     for n in range(n_policies):
         lg_n, v_n = _policy_cell(tuple(w[n] for w in stacked), x,
                                  fast_gates=fast_gates)
-        sel = pidx == n
-        lg = jnp.where(sel[:, None], lg_n, lg)
-        v = jnp.where(sel, v_n, v)
-    m = m_ref[...] != 0                                # (bs,)
-    lg_ref[...] = jnp.where(m[:, None], lg, 0.0).astype(lg_ref.dtype)
-    v_ref[...] = jnp.where(m, v, 0.0).astype(v_ref.dtype)
+        lg = jnp.where(pidx[:, None] == n, lg_n, lg)
+        v = jnp.where(pidx == n, v_n, v)
+    m = m_ref[...]                                     # (bs,) int32
+    lg_ref[...] = jnp.where(m[:, None] != 0, lg, 0.0).astype(lg_ref.dtype)
+    v_ref[...] = jnp.where(m != 0, v, 0.0).astype(v_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -344,7 +356,7 @@ def serve_forward_multi(frames, mask, pidx, pol_ws, *, fast_gates: bool,
             jax.ShapeDtypeStruct((S, n_act), jnp.float32),
             jax.ShapeDtypeStruct((S,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(),
+        compiler_params=pltpu.CompilerParams(),
         interpret=interpret,
     )(frames, mask, pidx, w1, b1, w2, b2, piw, pib, vw, vb)
     return logits, v
@@ -354,16 +366,77 @@ def serve_forward_multi(frames, mask, pidx, pol_ws, *, fast_gates: bool,
 # The whole-horizon rollout family: one kernel body, two cells, any A
 # ---------------------------------------------------------------------------
 
-def _rollout_kernel(*refs, n_ls: int, n_noise: int, n_w: int, T: int,
-                    cell_fn, tick_fn, dset_fn):
-    """Grid (A·B-blocks, T): lane blocks parallel-outer, horizon inner.
+# TPU layout of the rollout kernels' operands. Mosaic tiles the last two
+# dims of every block by (8, 128) unless the block spans the whole dim, so
+# the launchers hand the kernels (A, B, *s) lane arrays and (T, A, B, *s)
+# streams: one grid step's block is (block_b, *s) with the agent and time
+# dims squeezed, and it spans every feature dim. A lane leaf without
+# feature dims gets s = (1,) and is squeezed back to (block_b,) inside the
+# body. Stacked per-agent weights become (A, r, c) with the agent dim
+# squeezed (an (A, K) bias is (A, 1, K)); shared rank-1 weights become
+# (1, K).
 
-    Ref layout (positional): LS state leaves | AIP state s0 | n_w stacked
-    weights (leading per-agent block axis) | actions, bits | noise leaves
-    || final LS leaves, sT, rewards || scratch: AIP state, LS leaves.
-    The AIP recurrent state and every LS leaf live in VMEM scratch for the
-    whole T axis of a lane block; ``cell_fn`` (the backbone),
-    ``tick_fn``, and ``dset_fn`` are traced straight into this body."""
+def _to_kernel(x, A: int, n_lead: int):
+    """(*lead, L, *s) -> (*lead, A, L // A, *s), s = (1,) if empty."""
+    lead, L, s = x.shape[:n_lead], x.shape[n_lead], x.shape[n_lead + 1:]
+    return x.reshape(lead + (A, L // A) + (s or (1,)))
+
+
+def _stacked_w(w):
+    return w.reshape((w.shape[0], 1) + w.shape[1:]) if w.ndim == 2 else w
+
+
+def _shared_w(w):
+    return w.reshape(1, -1) if w.ndim == 1 else w
+
+
+def _lane_val(ref, flat: bool):
+    """Load one lane block: (block_b, *s), or (block_b,) for a leaf that
+    had no feature dims (``flat``)."""
+    v = ref[...]
+    return v.reshape(v.shape[0]) if flat else v
+
+
+def _put(ref, val):
+    ref[...] = val.reshape(ref.shape).astype(ref.dtype)
+
+
+def _lane_spec(x, block_b: int):
+    """(A, B, *s) lane array -> one t-invariant (block_b, *s) lane block."""
+    s = x.shape[2:]
+    return pl.BlockSpec((pl.squeezed, block_b) + s,
+                        lambda a, bi, t, _n=len(s): (a, bi) + (0,) * _n)
+
+
+def _stream_spec(x, block_b: int):
+    """(T, A, B, *s) stream -> one tick of a (block_b, *s) lane block."""
+    s = x.shape[3:]
+    return pl.BlockSpec((pl.squeezed, pl.squeezed, block_b) + s,
+                        lambda a, bi, t, _n=len(s): (t, a, bi) + (0,) * _n)
+
+
+def _agent_w_spec(w):
+    """(A, r, c) stacked weight -> the (r, c) weight of the block's agent."""
+    return pl.BlockSpec((pl.squeezed,) + w.shape[1:],
+                        lambda a, bi, t: (a, 0, 0))
+
+
+def _shared_w_spec(w):
+    """Shared weight -> the whole array, grid-invariant."""
+    return pl.BlockSpec(w.shape, lambda a, bi, t, _n=w.ndim: (0,) * _n)
+
+
+def _rollout_kernel(*refs, n_ls: int, n_noise: int, n_w: int, T: int,
+                    ls_flat, nz_flat, cell_fn, tick_fn, dset_fn):
+    """Grid (A, B-blocks, T): agents and lane blocks parallel, horizon
+    inner.
+
+    Ref layout (positional): LS state leaves | AIP state s0 | n_w
+    weights of this block's agent | actions, bits | noise leaves || final
+    LS leaves, sT, rewards || scratch: AIP state, LS leaves. The AIP
+    recurrent state and every LS leaf live in VMEM scratch for the whole
+    T axis of a lane block; ``cell_fn`` (the backbone), ``tick_fn``, and
+    ``dset_fn`` are traced straight into this body."""
     i = n_ls
     ls0 = refs[:n_ls]
     s0_ref = refs[i]
@@ -379,7 +452,7 @@ def _rollout_kernel(*refs, n_ls: int, n_noise: int, n_w: int, T: int,
     s_scr = refs[i]
     ls_scr = refs[i + 1:i + 1 + n_ls]
 
-    t = pl.program_id(1)
+    t = pl.program_id(2)
 
     @pl.when(t == 0)
     def _init():
@@ -387,23 +460,36 @@ def _rollout_kernel(*refs, n_ls: int, n_noise: int, n_w: int, T: int,
         for dst, src in zip(ls_scr, ls0):
             dst[...] = src[...]
 
-    ls_vals = tuple(s[...] for s in ls_scr)
-    a = a_ref[0]                                       # (Bblk,)
+    ls_vals = tuple(_lane_val(r, f) for r, f in zip(ls_scr, ls_flat))
+    a = _lane_val(a_ref, True)                         # (Bblk,)
     d = dset_fn(ls_vals, a).astype(jnp.float32)        # (Bblk, Dd)
-    w = tuple(r[0] for r in w_refs)                    # this block's agent
-    s2, _, u = cell_fn(w, s_scr[...], d, bits_ref[0])
+    w = tuple(r[...] for r in w_refs)                  # this block's agent
+    s2, _, u = cell_fn(w, s_scr[...], d, bits_ref[...])
     new_ls, rew = tick_fn(ls_vals, a, u,
-                          tuple(nr[0] for nr in noise_refs))
+                          tuple(_lane_val(r, f)
+                                for r, f in zip(noise_refs, nz_flat)))
     s_scr[...] = s2
     for dst, val in zip(ls_scr, new_ls):
-        dst[...] = val.astype(dst.dtype)
-    rew_ref[0] = rew.astype(rew_ref.dtype)
+        _put(dst, val)
+    _put(rew_ref, rew)
 
     @pl.when(t == T - 1)
     def _finish():
         sT_ref[...] = s_scr[...].astype(sT_ref.dtype)
         for dst, src in zip(ls_out, ls_scr):
             dst[...] = src[...]
+
+
+def _lane_geometry(L: int, n_agents: int, block_b: int | None):
+    if L % n_agents:
+        raise ValueError(f"lane count {L} not divisible by "
+                         f"n_agents={n_agents}")
+    B = L // n_agents
+    block_b = B if block_b is None else block_b
+    if B % block_b:
+        raise ValueError(f"block_b={block_b} must divide per-agent "
+                         f"batch {B}")
+    return B, block_b
 
 
 def _launch_rollout(cell_fn, ls, s0, weights, actions, bits, noise, *,
@@ -416,58 +502,39 @@ def _launch_rollout(cell_fn, ls, s0, weights, actions, bits, noise, *,
     stacked per-agent weight leaves; ``actions``: (T, L); ``bits``:
     (T, L, M); ``noise``: tuple of (T, L, ...) leaves.
     -> (final ls leaves, s_T (L, K), rewards (T, L) f32)."""
-    L = s0.shape[0]
-    A = n_agents
-    if L % A:
-        raise ValueError(f"lane count {L} not divisible by n_agents={A}")
-    B = L // A
-    T = actions.shape[0]
-    if block_b is None:
-        block_b = B
-    if B % block_b:
-        raise ValueError(f"block_b={block_b} must divide per-agent "
-                         f"batch {B}")
-    nB = B // block_b
-
-    def w_spec(leaf):          # (A, ...) stacked weight -> this agent's
-        s = leaf.shape[1:]
-        return pl.BlockSpec((1,) + s,
-                            lambda bi, t, _n=len(s): (bi // nB,)
-                            + (0,) * _n)
-
-    def state_spec(leaf):      # (L, ...) leaf -> per-block, t-invariant
-        s = leaf.shape[1:]
-        return pl.BlockSpec((block_b,) + s,
-                            lambda bi, t, _n=len(s): (bi,) + (0,) * _n)
-
-    def stream_spec(leaf):     # (T, L, ...) leaf -> one tick per grid step
-        s = leaf.shape[2:]
-        return pl.BlockSpec((1, block_b) + s,
-                            lambda bi, t, _n=len(s): (t, bi) + (0,) * _n)
-
-    kernel = functools.partial(_rollout_kernel, n_ls=len(ls),
-                               n_noise=len(noise), n_w=len(weights), T=T,
-                               cell_fn=cell_fn, tick_fn=tick_fn,
-                               dset_fn=dset_fn)
+    L, A, T = s0.shape[0], n_agents, actions.shape[0]
+    B, block_b = _lane_geometry(L, A, block_b)
+    k_ls = [_to_kernel(x, A, 0) for x in ls]
+    k_s0 = _to_kernel(s0, A, 0)
+    k_w = [_stacked_w(w) for w in weights]
+    k_streams = [_to_kernel(x, A, 1) for x in (actions, bits) + noise]
+    rew_shape = (T, A, B, 1)
+    kernel = functools.partial(
+        _rollout_kernel, n_ls=len(ls), n_noise=len(noise), n_w=len(weights),
+        T=T, ls_flat=tuple(x.ndim == 1 for x in ls),
+        nz_flat=tuple(x.ndim == 2 for x in noise), cell_fn=cell_fn,
+        tick_fn=tick_fn, dset_fn=dset_fn)
     out = pl.pallas_call(
         kernel,
-        grid=(A * nB, T),
-        in_specs=[state_spec(l) for l in ls] + [state_spec(s0)] + [
-            w_spec(w) for w in weights] + [
-            stream_spec(actions), stream_spec(bits),
-        ] + [stream_spec(n) for n in noise],
-        out_specs=[state_spec(l) for l in ls] + [
-            state_spec(s0), stream_spec(jnp.empty((T, L), jnp.float32))],
-        out_shape=[jax.ShapeDtypeStruct(l.shape, l.dtype) for l in ls] + [
-            jax.ShapeDtypeStruct(s0.shape, s0.dtype),
-            jax.ShapeDtypeStruct((T, L), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_b, s0.shape[1]), jnp.float32)] + [
-            pltpu.VMEM((block_b,) + l.shape[1:], l.dtype) for l in ls],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        grid=(A, B // block_b, T),
+        in_specs=[_lane_spec(x, block_b) for x in k_ls + [k_s0]]
+        + [_agent_w_spec(w) for w in k_w]
+        + [_stream_spec(x, block_b) for x in k_streams],
+        out_specs=[_lane_spec(x, block_b) for x in k_ls + [k_s0]]
+        + [_stream_spec(jax.ShapeDtypeStruct(rew_shape, jnp.float32),
+                        block_b)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in k_ls + [k_s0]]
+        + [jax.ShapeDtypeStruct(rew_shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_b, k_s0.shape[2]), jnp.float32)]
+        + [pltpu.VMEM((block_b,) + x.shape[2:], x.dtype) for x in k_ls],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*ls, s0, *weights, actions, bits, *noise)
-    return tuple(out[:len(ls)]), out[len(ls)], out[len(ls) + 1]
+    )(*k_ls, k_s0, *k_w, *k_streams)
+    n = len(ls)
+    return (tuple(o.reshape(x.shape) for o, x in zip(out[:n], ls)),
+            out[n].reshape(s0.shape), out[n + 1].reshape(T, L))
 
 
 @functools.partial(jax.jit, static_argnames=("n_agents", "tick_fn",
@@ -559,21 +626,20 @@ def aip_rollout(ls, h0, wx, wh, b, hw, hb, actions, bits, noise, *,
 # ---------------------------------------------------------------------------
 
 def _policy_rollout_kernel(*refs, n_ls: int, n_noise: int, n_w: int,
-                           T: int, cell_fn, pol_fn, tick_fn, dset_fn,
-                           obs_fn):
-    """Grid (A·B-blocks, T): one PPO acting tick per grid step.
+                           T: int, ls_flat, nz_flat, cell_fn, pol_fn,
+                           tick_fn, dset_fn, obs_fn):
+    """Grid (A, B-blocks, T): one PPO acting tick per grid step.
 
     Ref layout (positional): LS leaves | AIP state s0 | policy frame
-    stack f0 | n_w stacked AIP weights (per-agent block axis) | 8 shared
-    policy weights | gumbel, bits, done streams | noise leaves | reset
-    LS leaves || final LS leaves, sT, framesT, x, a, logits, v, rewards
-    || scratch: AIP state, frames, LS leaves. Per tick: policy forward
-    on the VMEM frame stack -> Gumbel-argmax action -> AIP cell +
-    Bernoulli draw -> LS transition -> observation refills the frame
-    stack -> the streamed ``done`` schedule merges in the streamed reset
-    state (AIP state back to zeros, frames re-seeded from the reset
-    observation). Only the PPO batch streams and final states leave
-    VMEM."""
+    stack f0 | n_w weights of this block's agent | 8 shared policy
+    weights | gumbel, bits, done streams | noise leaves | reset LS leaves
+    || final LS leaves, sT, framesT, x, a, logits, v, rewards || scratch:
+    AIP state, frames, LS leaves. Per tick: policy forward on the VMEM
+    frame stack -> Gumbel-argmax action -> AIP cell + Bernoulli draw -> LS
+    transition -> observation refills the frame stack -> the streamed
+    ``done`` schedule merges in the streamed reset state (AIP state back
+    to zeros, frames re-seeded from the reset observation). Only the PPO
+    batch streams and final states leave VMEM."""
     i = n_ls
     ls0 = refs[:n_ls]
     s0_ref, f0_ref = refs[i], refs[i + 1]
@@ -597,7 +663,7 @@ def _policy_rollout_kernel(*refs, n_ls: int, n_noise: int, n_w: int,
     s_scr, f_scr = refs[i], refs[i + 1]
     ls_scr = refs[i + 2:i + 2 + n_ls]
 
-    t = pl.program_id(1)
+    t = pl.program_id(2)
 
     @pl.when(t == 0)
     def _init():
@@ -608,37 +674,36 @@ def _policy_rollout_kernel(*refs, n_ls: int, n_noise: int, n_w: int,
 
     x = f_scr[...]                                     # (Bblk, S)
     logits, value = pol_fn(tuple(r[...] for r in pw_refs), x)
-    a = jnp.argmax(logits + gum_ref[0], axis=-1).astype(jnp.int32)
+    a = jnp.argmax(logits + gum_ref[...], axis=-1).astype(jnp.int32)
 
-    ls_vals = tuple(s[...] for s in ls_scr)
+    ls_vals = tuple(_lane_val(r, f) for r, f in zip(ls_scr, ls_flat))
     d = dset_fn(ls_vals, a).astype(jnp.float32)        # (Bblk, Dd)
-    w = tuple(r[0] for r in w_refs)                    # this block's agent
-    s2, _, u = cell_fn(w, s_scr[...], d, bits_ref[0])
+    w = tuple(r[...] for r in w_refs)                  # this block's agent
+    s2, _, u = cell_fn(w, s_scr[...], d, bits_ref[...])
     new_ls, rew = tick_fn(ls_vals, a, u,
-                          tuple(nr[0] for nr in noise_refs))
+                          tuple(_lane_val(r, f)
+                                for r, f in zip(noise_refs, nz_flat)))
     obs = obs_fn(new_ls).astype(jnp.float32)           # (Bblk, d_obs)
-    d_obs = obs.shape[-1]
-    frames2 = jnp.concatenate([x[:, d_obs:], obs], axis=1)
+    frames2 = _shift_in(x, obs)
 
-    dn = done_ref[0] != 0                              # (Bblk,)
-    ls_m = tuple(
-        jnp.where(dn.reshape((-1,) + (1,) * (n.ndim - 1)), r[0], n)
-        for n, r in zip(new_ls, reset_refs))
-    s_m = jnp.where(dn[:, None], jnp.zeros_like(s2), s2)
+    done = _lane_val(done_ref, True)                   # (Bblk,) int32
+
+    def at_done(v):        # reshape the int32 flag, not a bool vector
+        return done.reshape((-1,) + (1,) * (v.ndim - 1)) != 0
+
+    ls_m = tuple(jnp.where(at_done(n), _lane_val(r, f), n)
+                 for n, r, f in zip(new_ls, reset_refs, ls_flat))
+    s_m = jnp.where(at_done(s2), jnp.zeros_like(s2), s2)
     obs0 = obs_fn(ls_m).astype(jnp.float32)
-    frames_reset = jnp.concatenate(
-        [jnp.zeros_like(x[:, d_obs:]), obs0], axis=1)
-    f_m = jnp.where(dn[:, None], frames_reset, frames2)
+    f_m = jnp.where(at_done(x), _shift_in(jnp.zeros_like(x), obs0), frames2)
 
     s_scr[...] = s_m
     f_scr[...] = f_m
     for dst, val in zip(ls_scr, ls_m):
-        dst[...] = val.astype(dst.dtype)
-    x_ref[0] = x.astype(x_ref.dtype)
-    a_ref[0] = a
-    lg_ref[0] = logits.astype(lg_ref.dtype)
-    v_ref[0] = value.astype(v_ref.dtype)
-    rew_ref[0] = rew.astype(rew_ref.dtype)
+        _put(dst, val)
+    for ref, val in zip((x_ref, a_ref, lg_ref, v_ref, rew_ref),
+                        (x, a, logits, value, rew)):
+        _put(ref, val)
 
     @pl.when(t == T - 1)
     def _finish():
@@ -661,81 +726,54 @@ def _launch_policy_rollout(cell_fn, pol_fn, ls, s0, frames0, weights,
     ``reset_ls`` tuple of (T, L, ...) streamed reset-state leaves (same
     dtypes as ``ls``). -> (final ls leaves, s_T, frames_T, x (T, L, S),
     a (T, L) int32, logits (T, L, n_actions), v (T, L), r (T, L))."""
-    L = s0.shape[0]
-    A = n_agents
-    if L % A:
-        raise ValueError(f"lane count {L} not divisible by n_agents={A}")
-    B = L // A
-    T = gumbel.shape[0]
-    if block_b is None:
-        block_b = B
-    if B % block_b:
-        raise ValueError(f"block_b={block_b} must divide per-agent "
-                         f"batch {B}")
-    nB = B // block_b
+    L, A, T = s0.shape[0], n_agents, gumbel.shape[0]
+    B, block_b = _lane_geometry(L, A, block_b)
     S = frames0.shape[1]
     n_act = gumbel.shape[-1]
 
-    def w_spec(leaf):          # (A, ...) stacked weight -> this agent's
-        s = leaf.shape[1:]
-        return pl.BlockSpec((1,) + s,
-                            lambda bi, t, _n=len(s): (bi // nB,)
-                            + (0,) * _n)
-
-    def full_spec(leaf):       # shared weight -> whole array, invariant
-        return pl.BlockSpec(leaf.shape,
-                            lambda bi, t, _n=leaf.ndim: (0,) * _n)
-
-    def state_spec(leaf):      # (L, ...) leaf -> per-block, t-invariant
-        s = leaf.shape[1:]
-        return pl.BlockSpec((block_b,) + s,
-                            lambda bi, t, _n=len(s): (bi,) + (0,) * _n)
-
-    def stream_spec(leaf):     # (T, L, ...) leaf -> one tick per grid step
-        s = leaf.shape[2:]
-        return pl.BlockSpec((1, block_b) + s,
-                            lambda bi, t, _n=len(s): (t, bi) + (0,) * _n)
-
+    k_ls = [_to_kernel(x, A, 0) for x in ls]
+    k_state = [_to_kernel(x, A, 0) for x in (s0, frames0)]
+    k_w = [_stacked_w(w) for w in weights]
+    k_pw = [_shared_w(w) for w in pol_w]
+    k_streams = [_to_kernel(x, A, 1)
+                 for x in (gumbel, bits, done) + noise + reset_ls]
     stream_outs = [
-        jax.ShapeDtypeStruct((T, L, S), jnp.float32),       # x
-        jax.ShapeDtypeStruct((T, L), jnp.int32),            # a
-        jax.ShapeDtypeStruct((T, L, n_act), jnp.float32),   # logits
-        jax.ShapeDtypeStruct((T, L), jnp.float32),          # v
-        jax.ShapeDtypeStruct((T, L), jnp.float32),          # rewards
+        jax.ShapeDtypeStruct((T, A, B, S), jnp.float32),       # x
+        jax.ShapeDtypeStruct((T, A, B, 1), jnp.int32),         # a
+        jax.ShapeDtypeStruct((T, A, B, n_act), jnp.float32),   # logits
+        jax.ShapeDtypeStruct((T, A, B, 1), jnp.float32),       # v
+        jax.ShapeDtypeStruct((T, A, B, 1), jnp.float32),       # rewards
     ]
-    kernel = functools.partial(_policy_rollout_kernel, n_ls=len(ls),
-                               n_noise=len(noise), n_w=len(weights), T=T,
-                               cell_fn=cell_fn, pol_fn=pol_fn,
-                               tick_fn=tick_fn, dset_fn=dset_fn,
-                               obs_fn=obs_fn)
+    kernel = functools.partial(
+        _policy_rollout_kernel, n_ls=len(ls), n_noise=len(noise),
+        n_w=len(weights), T=T, ls_flat=tuple(x.ndim == 1 for x in ls),
+        nz_flat=tuple(x.ndim == 2 for x in noise), cell_fn=cell_fn,
+        pol_fn=pol_fn, tick_fn=tick_fn, dset_fn=dset_fn, obs_fn=obs_fn)
+    lane_arrays = k_ls + k_state
     out = pl.pallas_call(
         kernel,
-        grid=(A * nB, T),
-        in_specs=[state_spec(l) for l in ls]
-        + [state_spec(s0), state_spec(frames0)]
-        + [w_spec(w) for w in weights]
-        + [full_spec(w) for w in pol_w]
-        + [stream_spec(gumbel), stream_spec(bits), stream_spec(done)]
-        + [stream_spec(n) for n in noise]
-        + [stream_spec(r) for r in reset_ls],
-        out_specs=[state_spec(l) for l in ls]
-        + [state_spec(s0), state_spec(frames0)]
-        + [stream_spec(o) for o in stream_outs],
-        out_shape=[jax.ShapeDtypeStruct(l.shape, l.dtype) for l in ls]
-        + [jax.ShapeDtypeStruct(s0.shape, s0.dtype),
-           jax.ShapeDtypeStruct(frames0.shape, frames0.dtype)]
-        + stream_outs,
-        scratch_shapes=[pltpu.VMEM((block_b, s0.shape[1]), jnp.float32),
-                        pltpu.VMEM((block_b, S), jnp.float32)]
-        + [pltpu.VMEM((block_b,) + l.shape[1:], l.dtype) for l in ls],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        grid=(A, B // block_b, T),
+        in_specs=[_lane_spec(x, block_b) for x in lane_arrays]
+        + [_agent_w_spec(w) for w in k_w]
+        + [_shared_w_spec(w) for w in k_pw]
+        + [_stream_spec(x, block_b) for x in k_streams],
+        out_specs=[_lane_spec(x, block_b) for x in lane_arrays]
+        + [_stream_spec(o, block_b) for o in stream_outs],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in lane_arrays] + stream_outs,
+        scratch_shapes=[pltpu.VMEM((block_b, x.shape[2]), jnp.float32)
+                        for x in k_state]
+        + [pltpu.VMEM((block_b,) + x.shape[2:], x.dtype) for x in k_ls],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*ls, s0, frames0, *weights, *pol_w, gumbel, bits, done, *noise,
-      *reset_ls)
+    )(*lane_arrays, *k_w, *k_pw, *k_streams)
     nl = len(ls)
-    return (tuple(out[:nl]), out[nl], out[nl + 1], out[nl + 2],
-            out[nl + 3], out[nl + 4], out[nl + 5], out[nl + 6])
+    x, a, lg, v, r = out[nl + 2:]
+    return (tuple(o.reshape(l.shape) for o, l in zip(out[:nl], ls)),
+            out[nl].reshape(s0.shape), out[nl + 1].reshape(frames0.shape),
+            x.reshape(T, L, S), a.reshape(T, L), lg.reshape(T, L, n_act),
+            v.reshape(T, L), r.reshape(T, L))
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "n_agents",

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.nn.act import fast_sigmoid, fast_tanh
 
 
@@ -71,7 +70,7 @@ def gru_sequence(x, wx, wh, b, h0, *, interpret: bool | None = None):
         out_specs=pl.BlockSpec((B, 1, H), lambda t: (0, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, H), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, wx, wh, b, h0)

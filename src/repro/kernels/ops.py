@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On a real TPU runtime set ``interpret=False`` (the default flips on TPU
-backends automatically); in this CPU container interpret mode executes the
-kernel bodies in Python for correctness validation against ``ref.py``.
+On a TPU backend the production dispatch compiles the kernel
+(``interpret=False``); elsewhere hot-path ops run their ``ref.py`` oracle,
+and ``interpret=True`` executes the kernel bodies in Python for
+correctness validation against ``ref.py`` (ARCHITECTURE §2).
 """
 from __future__ import annotations
 
@@ -91,7 +92,7 @@ def ials_rollout_multi(ls, h0, wx, wh, b, hw, hb, actions, bits, noise, *,
                        interpret=None):
     """Whole-horizon fused IALS rollout, GRU backbone, A per-agent AIPs:
     T coupled AIP+LS ticks for every A·B agent-major lane in ONE kernel
-    dispatch (``aip_rollout_multi``'s (A·B-blocks, T) grid, per-agent
+    dispatch (``aip_rollout_multi``'s (A, B-blocks, T) grid, per-agent
     weights indexed by the agent coordinate of each lane block) on TPU;
     the identical-math ``ref.ials_rollout_multi_ref`` scan elsewhere.
     Both paths run the caller's ``tick_fn``/``dset_fn`` on the same
@@ -179,7 +180,7 @@ def policy_rollout(ls, s0, frames0, aip_w, pol_w, gumbel, bits, done,
     Gumbel-argmax action sampling on pre-drawn noise, the AIP backbone
     cell (``kind`` in {"gru", "fnn"}) with its Bernoulli draw, the LS
     transition + reward, and the periodic episode-reset merge — in ONE
-    kernel dispatch (``aip_step.policy_rollout``'s (A·B-blocks, T) grid)
+    kernel dispatch (``aip_step.policy_rollout``'s (A, B-blocks, T) grid)
     on TPU; the identical-math ``ref.policy_rollout_ref`` scan elsewhere.
     Both paths run the caller's ``tick_fn``/``dset_fn``/``obs_fn`` on the
     same values in the same order, so they agree bitwise given the same

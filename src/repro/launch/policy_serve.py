@@ -62,6 +62,7 @@ import jax
 from repro.checkpoint import ckpt
 from repro.distributed.fault_injection import (FaultInjector,
                                                parse_serve_faults)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.rl_train import build_domain
 from repro.rl import ppo
 from repro.serving import (BIMODAL_SIZES, BIMODAL_WEIGHTS,
@@ -174,6 +175,7 @@ def main(argv=None):
                     help="per-dispatch service time of the virtual clock")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     server, trace, info = build_server_and_trace(args)
     admission = None

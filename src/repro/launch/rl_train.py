@@ -14,8 +14,9 @@ every agent's (d_t, u_t) pairs, A per-agent AIPs train in a single batched
 pass (vmap of the training loop), PPO is parameter-shared across agents with
 the agent axis as extra batch dimension, and evaluation reports per-agent GS
 rewards. ``--n-agents 25`` on traffic = every intersection of the 5x5 grid;
-``--n-agents 36`` on warehouse = every robot region. Rollout batches are
-placed on the mesh ``data`` axis when more than one device is visible.
+``--n-agents 36`` on warehouse = every robot region. ``--devices N`` places
+the rollout batch on the ``data`` axis of an N-device mesh (default: one
+device, no mesh).
 
 Fault tolerance (the kill-and-resume contract, docs/ARCHITECTURE.md §7):
 ``--ckpt-dir`` makes the run preemption-safe — every RNG key is derived by
@@ -67,6 +68,7 @@ from repro.envs.warehouse import (WarehouseConfig, make_warehouse_env,
                                   make_batched_local_warehouse_env,
                                   make_local_warehouse_env,
                                   make_multi_warehouse_env)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.rl import ppo
 
@@ -108,7 +110,8 @@ def build_domain(domain: str, vanish_after: int = 0, n_agents: int = 1):
 def _make_sim(ls, params, acfg, n_agents, **kw):
     """``ls``: a BatchedLocalEnv — PPO trains on the unified fused rollout
     engine (one implementation for every backbone x agent-multiplicity
-    combination; single-agent is the A=1 squeeze)."""
+    combination; single-agent is the A=1 squeeze). ``mesh`` (in ``kw``)
+    runs its kernel route on each device's own env lanes."""
     return engine.make_unified_ials(ls, params, acfg, n_agents=n_agents,
                                     **kw)
 
@@ -127,10 +130,11 @@ class SimBuild(NamedTuple):
 def prepare_simulator(simulator: str, gs, ls, aip_kind: str, *,
                       collect_episodes: int, ep_len: int, aip_epochs: int,
                       fixed_marginal=None, aip_window: int = 0,
-                      stateless_f_ials: bool = False) -> SimBuild:
+                      stateless_f_ials: bool = False,
+                      mesh=None) -> SimBuild:
     """-> SimBuild. ``stateless_f_ials`` makes the f-ials simulator skip
     its (ignored) AIP forward pass entirely — see ``ials.make_ials`` for
-    the state-shape-parity tradeoff."""
+    the state-shape-parity tradeoff. ``mesh`` is handed to the engine."""
     if simulator == "gs":
         return SimBuild(template=lambda: {},
                         train=lambda key: ({}, {}),
@@ -169,7 +173,7 @@ def prepare_simulator(simulator: str, gs, ls, aip_kind: str, *,
         return SimBuild(
             template=lambda: init_params(jax.random.PRNGKey(0)),
             train=train,
-            make_env=lambda p: _make_sim(ls, p, acfg, A))
+            make_env=lambda p: _make_sim(ls, p, acfg, A, mesh=mesh))
 
     if simulator == "f-ials":
         M = gs.spec.n_influence
@@ -200,7 +204,8 @@ def prepare_simulator(simulator: str, gs, ls, aip_kind: str, *,
             train=train,
             make_env=lambda p: _make_sim(ls, p["aip"], acfg, A,
                                          fixed_marginal_vec=p["marg"],
-                                         stateless=stateless_f_ials))
+                                         stateless=stateless_f_ials,
+                                         mesh=mesh))
 
     # trained IALS (the dataset is dead after the fit -> donate the
     # epoch buffers to the jitted training loop)
@@ -223,7 +228,7 @@ def prepare_simulator(simulator: str, gs, ls, aip_kind: str, *,
         return params, diag
     return SimBuild(template=lambda: init_params(jax.random.PRNGKey(0)),
                     train=train,
-                    make_env=lambda p: _make_sim(ls, p, acfg, A))
+                    make_env=lambda p: _make_sim(ls, p, acfg, A, mesh=mesh))
 
 
 def build_simulator(simulator: str, gs, ls, aip_kind: str, key, *,
@@ -268,11 +273,15 @@ def run_training(args):
     gs, _, ls, frame_stack = build_domain(args.domain, args.vanish_after,
                                           args.n_agents)
     aip_kind = args.aip or ("gru" if args.domain == "warehouse" else "fnn")
+    # one device unless the caller asks for more: env lanes then shard
+    # over a (data, 1) mesh of the first --devices local devices
+    mesh = make_host_mesh(n_devices=args.devices) if args.devices > 1 \
+        else None
     sb = prepare_simulator(
         args.simulator, gs, ls, aip_kind,
         collect_episodes=args.collect_episodes, ep_len=args.episode_len,
         aip_epochs=args.aip_epochs, fixed_marginal=args.fixed_marginal,
-        stateless_f_ials=args.stateless_f_ials)
+        stateless_f_ials=args.stateless_f_ials, mesh=mesh)
 
     pcfg = ppo.PPOConfig(obs_dim=gs.spec.obs_dim,
                          n_actions=gs.spec.n_actions,
@@ -281,9 +290,6 @@ def run_training(args):
                          episode_len=args.episode_len,
                          n_agents=args.n_agents,
                          fast_gates=not args.exact_policy_tanh)
-    mesh = (make_host_mesh()
-            if len(jax.devices()) > 1
-            and args.n_envs % len(jax.devices()) == 0 else None)
     t_start = time.time()
     guard = (TrainingGuard(args.ckpt_dir, save_every=args.save_every)
              if args.ckpt_dir else None)
@@ -349,6 +355,10 @@ def _run_integrated(args, root, sb: SimBuild, pcfg, mesh, guard,
         rs = ppo.init_rollout_state(env, pcfg,
                                     jax.random.fold_in(root, _K_ROLLOUT),
                                     mesh=mesh)
+    # every argument placed where train_iteration returns it (a restored
+    # checkpoint lands on one device): one program for every iteration
+    params, ost = ppo.replicate((params, ost), mesh)
+    rs = ppo.shard_rollout(rs, mesh, n_agents=pcfg.n_agents)
 
     steps_per_iter = args.n_envs * args.rollout_len * max(args.n_agents, 1)
     history = []
@@ -487,6 +497,9 @@ def main(argv=None):
     ap.add_argument("--aip-epochs", type=int, default=10)
     ap.add_argument("--vanish-after", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=1,
+                    help="local devices to shard env lanes over (1 = a "
+                         "single device, no mesh)")
     ap.add_argument("--out", default="")
     # fault tolerance / disaggregation
     ap.add_argument("--ckpt-dir", default="",
@@ -514,6 +527,7 @@ def main(argv=None):
                     help="hold the batch worker W produces at TICK for N "
                          "ticks (drives it past --max-staleness)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     return run_training(args)
 
 

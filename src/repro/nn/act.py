@@ -45,5 +45,10 @@ def uniform_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
     ``uniform_from_bits(bits) < p`` is an unbiased Bernoulli(p) draw up to
     2^-24 probability quantisation. This is the shared threshold-compare
     convention of the fused AIP step (kernel and oracle alike).
+
+    The cast goes through int32 because Mosaic has no uint32 -> float32
+    conversion; the top 24 bits fit a signed int32, so the values are
+    unchanged.
     """
-    return (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    top24 = (bits >> 8).astype(jnp.int32)
+    return top24.astype(jnp.float32) * (1.0 / (1 << 24))

@@ -211,6 +211,17 @@ def shard_rollout(rs: RolloutState, mesh,
     return shd.shard_ials_state(rs, mesh, n_agents)
 
 
+def replicate(tree, mesh):
+    """Place policy / optimizer state replicated on the mesh, the sharding
+    ``train_iteration`` returns them with, so the second iteration runs
+    the first one's program instead of compiling another for a new input
+    sharding. No-op for ``mesh=None``."""
+    if mesh is None:
+        return tree
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.device_put(tree, NamedSharding(mesh, PartitionSpec()))
+
+
 def _split_tick_keys(key, T: int):
     """The per-tick (action, env, reset) keys, pre-split outside the scan —
     the same values the historical in-body ``jax.random.split(k, 3)``

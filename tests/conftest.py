@@ -7,12 +7,16 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+
 jax.config.update("jax_enable_x64", False)
 
 # Identical programs re-jitted from fresh closures (every test builds its own
 # env/step fns) hit this cache instead of recompiling — cuts the tier-1 wall
-# clock severalfold, both within a run and across runs.
-jax.config.update("jax_compilation_cache_dir", "/tmp/repro-jax-test-cache")
+# clock severalfold, both within a run and across runs. The directory is the
+# program's own rule: JAX_COMPILATION_CACHE_DIR, else the checkout's
+# .jax_cache/.
+enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 

@@ -292,7 +292,7 @@ def test_rl_train_resume_bitwise_on_8_device_mesh(tmp_path):
                 "--iterations", "3", "--eval-every", "100",
                 "--n-envs", "8", "--rollout-len", "8",
                 "--episode-len", "16", "--collect-episodes", "2",
-                "--aip-epochs", "1", "--seed", "4"]
+                "--aip-epochs", "1", "--seed", "4", "--devices", "8"]
         full = rl_train.main(base)
         part = rl_train.main(base[:5] + ["1"] + base[6:]
                              + ["--ckpt-dir", ckdir, "--save-every", "1"])

@@ -79,9 +79,9 @@ def test_multi_device_dryrun_cell():
         import jax
         import repro.launch.mesh as mesh_mod
         mesh_mod.make_production_mesh = (
-            lambda multi_pod=False: jax.make_mesh((2, 2, 2),
-                                                  ("pod", "data", "model"))
-            if multi_pod else jax.make_mesh((4, 2), ("data", "model")))
+            lambda multi_pod=False: mesh_mod.make_mesh(
+                (2, 2, 2), ("pod", "data", "model"))
+            if multi_pod else mesh_mod.make_mesh((4, 2), ("data", "model")))
         from repro.launch.dryrun import run_cell
         r1 = run_cell("whisper-base", "train_4k", "pod1")
         r2 = run_cell("whisper-base", "decode_32k", "pod2")
@@ -179,6 +179,38 @@ def test_ials_state_specs_divide_or_replicate(domain, backbone, A):
                         x, jax.sharding.PartitionSpec))):
             _assert_divides(leaf, spec, mesh,
                             (domain, backbone, A, mesh.shape, path))
+
+
+def test_host_mesh_axes_are_auto():
+    """Meshes from launch/mesh.py have Auto axes — the only kind
+    ``with_sharding_constraint`` accepts — and take the first n devices;
+    asking for more devices than exist is an error."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(n_devices=1)
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert mesh.devices.size == 1
+    with pytest.raises(ValueError):
+        make_host_mesh(n_devices=len(jax.devices()) + 1)
+
+
+def test_ials_kernel_specs_match_state_rules():
+    """The kernel route's shard_map specs take the same lane and agent
+    axes as the state rules, for every operand kind."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed import sharding as shd
+    mesh = _HostMesh(4, 2)
+    s4 = shd.ials_kernel_specs(16, 4, mesh)
+    assert s4 == {"state": P("data", "model"),
+                  "stream": P(None, "data", "model"),
+                  "lanes": P("data"), "agent": P("model"), "rep": P()}
+    s25 = shd.ials_kernel_specs(16, 25, mesh)     # agents replicate
+    assert s25["state"] == P(("data", "model"))
+    assert s25["agent"] == P()
+    s1 = shd.ials_kernel_specs(16, 1, mesh)
+    assert s1["stream"] == P(None, ("data", "model"))
+    assert s1["agent"] == P()
 
 
 def test_ials_lanes_shard_and_agents_coshard():
