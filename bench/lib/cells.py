@@ -1,0 +1,68 @@
+"""Find a cell's configuration, traffic mix, limits and metric readers by
+name. Everything is data under ``bench/``:
+
+- ``BENCHMARK.json`` (repo root): cells (``workloads``) and metrics;
+- ``bench/configs/<config>.json``: a configuration's sizes;
+- ``bench/mixes/<traffic>.json``: a traffic mix's parameters;
+- ``bench/limits/<cell>.json``: the limits ``correct`` is held to;
+- ``bench/metrics/<metric>.py``: a per-layer metric's reader, a module
+  with ``read(run) -> float | None``.
+
+A new cell, configuration, mix or metric is a new file and a new entry;
+no existing file changes.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+from pathlib import Path
+
+BENCH_DIR = Path(__file__).resolve().parents[1]
+ROOT = BENCH_DIR.parent
+
+
+def _load_json(path: Path) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def benchmark(root: Path = ROOT) -> dict:
+    return _load_json(root / "BENCHMARK.json")
+
+
+def cell(name: str, root: Path = ROOT) -> dict:
+    """-> the cell's ``workloads`` entry; KeyError if there is none."""
+    for w in benchmark(root)["workloads"]:
+        if w["name"] == name:
+            return w
+    raise KeyError(f"no workload named {name!r} in BENCHMARK.json")
+
+
+def config(name: str) -> dict:
+    return _load_json(BENCH_DIR / "configs" / f"{name}.json")
+
+
+def mix(name: str) -> dict:
+    return _load_json(BENCH_DIR / "mixes" / f"{name}.json")
+
+
+def limits(cell_name: str) -> dict:
+    return _load_json(BENCH_DIR / "limits" / f"{cell_name}.json")
+
+
+def metrics_of(cell_name: str, kind: str, root: Path = ROOT) -> list:
+    """The metric entries a cell reports: ``kind`` is "end_to_end" or
+    "per_layer". A metric without a ``workloads`` key is reported in every
+    cell."""
+    return [m for m in benchmark(root)[kind]
+            if cell_name in m.get("workloads", [cell_name])]
+
+
+def reader(metric: str):
+    """-> the ``read`` function of ``bench/metrics/<metric>.py``."""
+    path = BENCH_DIR / "metrics" / f"{metric}.py"
+    spec = importlib.util.spec_from_file_location(
+        "bench_metric_" + metric.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
