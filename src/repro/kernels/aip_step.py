@@ -56,6 +56,11 @@ GRU weights are laid out (D, 3H)/(H, 3H) gate-major [r|z|n] like
 kernels; activations are the shared rational gates from ``repro.nn.act``
 (identical in the oracles), so kernel-vs-oracle agreement is exact up to
 matmul association order.
+
+Each ``pallas_call`` carries a fixed ``name`` — ``aip_step``,
+``serve_forward``, ``serve_forward_multi``, ``aip_rollout`` (the GRU and
+FNN rollout family) and ``policy_rollout`` — so a device trace names a
+kernel the same whatever its Python kernel function is called.
 """
 from __future__ import annotations
 
@@ -173,6 +178,7 @@ def aip_step(d, h, wx, wh, b, hw, hb, bits, *, interpret: bool | None = None):
     kernel = functools.partial(_aip_step_kernel, H=H)
     h2, logits, u = pl.pallas_call(
         kernel,
+        name="aip_step",
         in_specs=[
             pl.BlockSpec((B, D), lambda: (0, 0)),
             pl.BlockSpec((B, H), lambda: (0, 0)),
@@ -247,6 +253,7 @@ def serve_forward(frames, mask, pol_w, *, fast_gates: bool,
     Hp = w1.shape[1]
     logits, v = pl.pallas_call(
         kernel,
+        name="serve_forward",
         grid=(S // bs,),
         in_specs=[
             pl.BlockSpec((bs, D), lambda i: (i, 0)),
@@ -334,6 +341,7 @@ def serve_forward_multi(frames, mask, pidx, pol_ws, *, fast_gates: bool,
     Hp = w1.shape[2]
     logits, v = pl.pallas_call(
         kernel,
+        name="serve_forward_multi",
         grid=(S // bs,),
         in_specs=[
             pl.BlockSpec((bs, D), lambda i: (i, 0)),
@@ -516,6 +524,7 @@ def _launch_rollout(cell_fn, ls, s0, weights, actions, bits, noise, *,
         tick_fn=tick_fn, dset_fn=dset_fn)
     out = pl.pallas_call(
         kernel,
+        name="aip_rollout",
         grid=(A, B // block_b, T),
         in_specs=[_lane_spec(x, block_b) for x in k_ls + [k_s0]]
         + [_agent_w_spec(w) for w in k_w]
@@ -752,6 +761,7 @@ def _launch_policy_rollout(cell_fn, pol_fn, ls, s0, frames0, weights,
     lane_arrays = k_ls + k_state
     out = pl.pallas_call(
         kernel,
+        name="policy_rollout",
         grid=(A, B // block_b, T),
         in_specs=[_lane_spec(x, block_b) for x in lane_arrays]
         + [_agent_w_spec(w) for w in k_w]

@@ -38,6 +38,15 @@ permutation gather per epoch and stream contiguous slices through the
 update scan (no per-minibatch gather copies), and ``train_iteration``
 donates its (params, opt_state, rollout-state) arguments so each PPO
 iteration updates in place instead of round-tripping fresh buffers.
+
+Phases: every op of an iteration runs under one ``jax.named_scope``,
+which reaches the compiled ops' ``op_name`` (the innermost one counts)
+and so names each op's phase in a device trace — ``ppo.noise`` (key
+splits, the pre-drawn Gumbel, env and reset noise), ``ppo.rollout`` (the
+acting loop and the bootstrap value, whatever implements it),
+``ppo.gae`` (GAE and the flatten), ``ppo.shuffle`` (each epoch's
+permutation and gathers) and ``ppo.update`` (the minibatch gradient
+steps and the epoch loop around them).
 """
 from __future__ import annotations
 
@@ -259,7 +268,6 @@ def rollout(env, cfg: PPOConfig, params, rs: RolloutState, key):
     whole_horizon = (benv.step_det is not None
                      and benv.noise_fn is not None)
     hoist = cfg.hoist_rollout_noise and whole_horizon
-    ka, ks, kr = _split_tick_keys(key, cfg.rollout_len)
 
     def finish_tick(rs, x, logits, value, a, env_state, obs, r,
                     reset_state):
@@ -288,51 +296,55 @@ def rollout(env, cfg: PPOConfig, params, rs: RolloutState, key):
                "done": done_b.astype(jnp.float32)}
         return RolloutState(env_state, frames, t), out
 
-    if hoist:
-        gum = bulk_gumbel(
-            ka, (cfg.n_envs,) + cfg.agent_shape + (cfg.n_actions,))
-        env_noise = horizon_noise(benv.noise_fn, ks, cfg.n_envs)
-        reset_states = jax.vmap(lambda k: benv.reset(k, cfg.n_envs))(kr)
+    def step_h(carry, xs):
+        rs = carry
+        g, n, reset_state = xs
+        x = _stack_obs(rs.frames)
+        logits, value = policy_forward(params, x,
+                                       fast_gates=cfg.fast_gates)
+        a = gumbel_argmax(logits, g)
+        env_state, obs, r, _ = benv.step_det(rs.env_state, a, n)
+        return finish_tick(rs, x, logits, value, a, env_state,
+                           obs, r, reset_state)
 
-        if benv.policy_rollout is not None:
+    def step_k(carry, xs):
+        rs = carry
+        ka, ks, kr = xs
+        x = _stack_obs(rs.frames)
+        logits, value = policy_forward(params, x,
+                                       fast_gates=cfg.fast_gates)
+        a = jax.random.categorical(ka, logits)
+        if whole_horizon:
+            env_state, obs, r, _ = benv.step_det(rs.env_state, a, ks)
+        else:
+            env_state, obs, r, _ = benv.step(rs.env_state, a, ks)
+        reset_state = benv.reset(kr, cfg.n_envs)
+        return finish_tick(rs, x, logits, value, a, env_state, obs,
+                           r, reset_state)
+
+    with jax.named_scope("ppo.noise"):
+        ka, ks, kr = _split_tick_keys(key, cfg.rollout_len)
+        if hoist:
+            gum = bulk_gumbel(
+                ka, (cfg.n_envs,) + cfg.agent_shape + (cfg.n_actions,))
+            env_noise = horizon_noise(benv.noise_fn, ks, cfg.n_envs)
+            reset_states = jax.vmap(lambda k: benv.reset(k, cfg.n_envs))(kr)
+        else:
+            env_xs = (horizon_noise(benv.noise_fn, ks, cfg.n_envs)
+                      if whole_horizon else ks)
+
+    with jax.named_scope("ppo.rollout"):
+        if hoist and benv.policy_rollout is not None:
             rs, batch = _engine_policy_rollout(
                 benv, cfg, params, rs, gum, env_noise, reset_states)
-        else:
-            def step_h(carry, xs):
-                rs = carry
-                g, n, reset_state = xs
-                x = _stack_obs(rs.frames)
-                logits, value = policy_forward(params, x,
-                                               fast_gates=cfg.fast_gates)
-                a = gumbel_argmax(logits, g)
-                env_state, obs, r, _ = benv.step_det(rs.env_state, a, n)
-                return finish_tick(rs, x, logits, value, a, env_state,
-                                   obs, r, reset_state)
-
+        elif hoist:
             rs, batch = lax.scan(step_h, rs,
                                  (gum, env_noise, reset_states))
-    else:
-        def step_k(carry, xs):
-            rs = carry
-            ka, ks, kr = xs
-            x = _stack_obs(rs.frames)
-            logits, value = policy_forward(params, x,
-                                           fast_gates=cfg.fast_gates)
-            a = jax.random.categorical(ka, logits)
-            if whole_horizon:
-                env_state, obs, r, _ = benv.step_det(rs.env_state, a, ks)
-            else:
-                env_state, obs, r, _ = benv.step(rs.env_state, a, ks)
-            reset_state = benv.reset(kr, cfg.n_envs)
-            return finish_tick(rs, x, logits, value, a, env_state, obs,
-                               r, reset_state)
-
-        env_xs = (horizon_noise(benv.noise_fn, ks, cfg.n_envs)
-                  if whole_horizon else ks)
-        rs, batch = lax.scan(step_k, rs, (ka, env_xs, kr))
-
-    x_last = _stack_obs(rs.frames)
-    _, v_last = policy_forward(params, x_last, fast_gates=cfg.fast_gates)
+        else:
+            rs, batch = lax.scan(step_k, rs, (ka, env_xs, kr))
+        x_last = _stack_obs(rs.frames)
+        _, v_last = policy_forward(params, x_last,
+                                   fast_gates=cfg.fast_gates)
     return rs, batch, v_last
 
 
@@ -428,15 +440,16 @@ def learner_update_fn(cfg: PPOConfig, opt):
     fault-tolerance contract)."""
 
     def learner_update(params, opt_state, batch, v_last, key):
-        adv, ret = gae(batch, v_last, cfg.gamma, cfg.lam)
-        total = batch["a"].size          # T * n_envs * n_agents samples
-        flat = {
-            "x": batch["x"].reshape(total, -1),
-            "a": batch["a"].reshape(total),
-            "logp": batch["logp"].reshape(total),
-            "adv": adv.reshape(total),
-            "ret": ret.reshape(total),
-        }
+        with jax.named_scope("ppo.gae"):
+            adv, ret = gae(batch, v_last, cfg.gamma, cfg.lam)
+            total = batch["a"].size      # T * n_envs * n_agents samples
+            flat = {
+                "x": batch["x"].reshape(total, -1),
+                "a": batch["a"].reshape(total),
+                "logp": batch["logp"].reshape(total),
+                "adv": adv.reshape(total),
+                "ret": ret.reshape(total),
+            }
         n_mb = cfg.n_minibatches
         mb_size = total // n_mb
 
@@ -445,10 +458,11 @@ def learner_update_fn(cfg: PPOConfig, opt):
             # ONE permutation gather per epoch; the scan then streams
             # contiguous (mb_size, ...) slices — no per-minibatch gather
             # copies (same minibatch contents as gathering row-by-row)
-            perm = jax.random.permutation(k, total)[:n_mb * mb_size]
-            shuf = jax.tree_util.tree_map(
-                lambda v: v[perm].reshape((n_mb, mb_size)
-                                          + v.shape[1:]), flat)
+            with jax.named_scope("ppo.shuffle"):
+                perm = jax.random.permutation(k, total)[:n_mb * mb_size]
+                shuf = jax.tree_util.tree_map(
+                    lambda v: v[perm].reshape((n_mb, mb_size)
+                                              + v.shape[1:]), flat)
 
             def mb_step(carry, mb):
                 params, opt_state = carry
@@ -461,11 +475,15 @@ def learner_update_fn(cfg: PPOConfig, opt):
                                                (params, opt_state), shuf)
             return (params, opt_state), ls.mean()
 
-        (params, opt_state), losses = lax.scan(
-            epoch, (params, opt_state), jax.random.split(key, cfg.epochs))
-        metrics = {"loss": losses.mean(),
-                   "mean_reward": batch["r"].mean(),
-                   "mean_value": batch["v"].mean()}
+        with jax.named_scope("ppo.shuffle"):
+            epoch_keys = jax.random.split(key, cfg.epochs)
+        # the epoch loop is the update's; its shuffle is scoped inside
+        with jax.named_scope("ppo.update"):
+            (params, opt_state), losses = lax.scan(
+                epoch, (params, opt_state), epoch_keys)
+            metrics = {"loss": losses.mean(),
+                       "mean_reward": batch["r"].mean(),
+                       "mean_value": batch["v"].mean()}
         return params, opt_state, metrics
 
     return learner_update
@@ -488,7 +506,8 @@ def train_iteration_fn(env, cfg: PPOConfig, opt, mesh=None):
         if mesh is not None:
             from repro.distributed import sharding as shd
             rs = shd.constrain_ials_state(rs, mesh, cfg.n_agents)
-        k_roll, k_upd = jax.random.split(key)
+        with jax.named_scope("ppo.noise"):
+            k_roll, k_upd = jax.random.split(key)
         rs, batch, v_last = rollout(env, cfg, params, rs, k_roll)
         params, opt_state, metrics = learner_update(
             params, opt_state, batch, v_last, k_upd)

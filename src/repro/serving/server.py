@@ -73,6 +73,14 @@ pays its queueing delay in full, and arrivals never throttle to the
 server's pace. ``mode="virtual"`` replaces the wall clock with a fixed
 per-dispatch service time so scheduler tests — and every overload /
 fault-injection decision — are deterministic.
+
+Host spans (``jax.profiler.TraceAnnotation``, on the profiler's clock
+with the device's ops; free but for well under a microsecond each when
+no trace is active): ``serve.dispatch`` per dispatch (its index and real
+lanes attached) holding ``serve.pop``, ``serve.pack``, ``serve.forward``
+(itself ``serve.put``, ``serve.launch``, ``serve.wait``) and
+``serve.complete``; ``serve.admit`` around each pass that admits due
+arrivals; ``serve.idle`` around the open-loop sleep to the next arrival.
 """
 from __future__ import annotations
 
@@ -99,6 +107,18 @@ HIST_BINS = 8
 LIFECYCLE = ("warming", "serving", "draining", "drained")
 
 
+def _span(name: str, **kwargs):
+    """A host span ``name`` in the profiler's trace around a phase of the
+    serving loop. With no trace active it costs well under a
+    microsecond, and ``kwargs`` are formatted only while one is."""
+    return jax.profiler.TraceAnnotation(name, **kwargs)
+
+
+def _lag(arrivals, t: float) -> Tuple[float, float]:
+    """-> (sum, max) over ``arrivals`` of ``t`` less the arrival."""
+    return len(arrivals) * t - sum(arrivals), t - min(arrivals)
+
+
 @dataclass
 class ServeStats:
     """Padding-waste + overload observability, accumulated per replay.
@@ -116,10 +136,16 @@ class ServeStats:
     ``rejected_by_reason`` (queue_full / brownout / infeasible) and
     ``shed_by_class`` breakdowns, plus the replay's hot-reload outcomes
     (``reloads`` accepted, ``reload_rejected`` rolled back) and the
-    lifecycle state at snapshot time (``final_state``). Every ratio is
-    guarded for the zero-dispatch replay (empty or fully shed trace):
-    ``summary()`` on a fresh instance is all zeros/empties, never a
-    division error."""
+    lifecycle state at snapshot time (``final_state``). The wait
+    counters, on the replay loop's clock (s from its start): the sum and
+    the largest, over requests, of ``admit_lag`` (the clock when the
+    loop took the request from the trace, admitted or shed, less its
+    arrival: how late the open loop noticed it) and of ``queue_wait``
+    (the clock when its dispatch was popped less its arrival), recorded
+    by ``record_admit`` / ``record_wait``. Every ratio is guarded for
+    the zero-dispatch replay (empty or fully shed trace): ``summary()``
+    on a fresh instance is all zeros/empties, never a division
+    error."""
     dispatches_by_slot: Dict[int, int] = field(default_factory=dict)
     lanes_by_slot: Dict[int, int] = field(default_factory=dict)
     occupancy_hist_by_slot: Dict[int, List[int]] = field(
@@ -130,6 +156,10 @@ class ServeStats:
     reloads: int = 0
     reload_rejected: int = 0
     final_state: str = ""
+    admit_lag_s: float = 0.0
+    admit_lag_max_s: float = 0.0
+    queue_wait_s: float = 0.0
+    queue_wait_max_s: float = 0.0
 
     def record(self, shape: int, n: int) -> None:
         self.dispatches_by_slot[shape] = (
@@ -146,6 +176,20 @@ class ServeStats:
         self.rejected_by_reason[reason] = (
             self.rejected_by_reason.get(reason, 0) + 1)
         self.shed_by_class[klass] = self.shed_by_class.get(klass, 0) + 1
+
+    def record_admit(self, now: float, arrivals: List[float]) -> None:
+        """The loop took requests arriving at ``arrivals`` from the trace
+        at ``now``."""
+        total, most = _lag(arrivals, now)
+        self.admit_lag_s += total
+        self.admit_lag_max_s = max(self.admit_lag_max_s, most)
+
+    def record_wait(self, t_pop: float, arrivals: List[float]) -> None:
+        """One dispatch of requests arriving at ``arrivals`` was popped
+        at ``t_pop``."""
+        total, most = _lag(arrivals, t_pop)
+        self.queue_wait_s += total
+        self.queue_wait_max_s = max(self.queue_wait_max_s, most)
 
     @property
     def dispatches(self) -> int:
@@ -184,6 +228,10 @@ class ServeStats:
             "reloads": self.reloads,
             "reload_rejected": self.reload_rejected,
             "final_state": self.final_state,
+            "admit_lag_s": self.admit_lag_s,
+            "admit_lag_max_s": self.admit_lag_max_s,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_wait_max_s": self.queue_wait_max_s,
         }
 
 
@@ -349,15 +397,21 @@ class PolicyServer:
         ``pidx`` (shape,) int32 routes each lane to its checkpoint on a
         multi-policy server (zeros — checkpoint 0 — when omitted).
         Pad-lane outputs are zeros (and action 0) by the kernel-boundary
-        mask — garbage by contract."""
-        frames = jnp.asarray(frames)
-        shape = frames.shape[0]
-        if pidx is None:
-            pidx = jnp.zeros((shape,), jnp.int32)
-        out = self._fwd(frames, pad_mask(n_valid, shape),
-                        jnp.asarray(pidx, dtype=jnp.int32), self._weights)
+        mask — garbage by contract. Host spans: ``serve.put`` (inputs to
+        the device), ``serve.launch`` (the jitted call until it returns),
+        ``serve.wait`` (until the outputs are ready)."""
+        with _span("serve.put"):
+            frames = jnp.asarray(frames)
+            shape = frames.shape[0]
+            if pidx is None:
+                pidx = jnp.zeros((shape,), jnp.int32)
+            mask = pad_mask(n_valid, shape)
+            pidx = jnp.asarray(pidx, dtype=jnp.int32)
+        with _span("serve.launch"):
+            out = self._fwd(frames, mask, pidx, self._weights)
         self._warmed.add(shape)
-        return jax.block_until_ready(out)
+        with _span("serve.wait"):
+            return jax.block_until_ready(out)
 
     def warmup(self, shapes: Optional[Sequence[int]] = None) -> None:
         """Compile every slot program before the serving clock starts —
@@ -516,26 +570,39 @@ class PolicyServer:
     def _dispatch_once(self, sched, stats: ServeStats,
                        latencies: List[float], now: float, mode: str,
                        service_time_s: float, t_start: float,
-                       extra_s: float) -> float:
+                       extra_s: float) -> Tuple[float, float, int]:
         """Pop + pack + forward one batch, advance the clock (virtual:
         ``service_time_s + extra_s``; wallclock: real time plus a
         slept ``extra_s``), complete the batch -> (new now, measured
-        dispatch seconds)."""
-        t_disp = time.perf_counter()
-        shape, batch = sched.next_dispatch()
-        frames, pidx = self._pack(batch, shape)
-        self.forward_slot(frames, len(batch), pidx)
-        if mode == "wallclock":
-            if extra_s > 0:
-                time.sleep(extra_s)
-            now = time.perf_counter() - t_start
-            dt = time.perf_counter() - t_disp
-        else:
-            dt = service_time_s + extra_s
-            now = now + dt
-        sched.complete(batch, now)
-        stats.record(shape, len(batch))
-        latencies.extend(now - r.arrival for r in batch)
+        dispatch seconds, slot shape). One ``serve.dispatch`` host span
+        (its dispatch index and real lanes attached) holds
+        ``serve.pop``, ``serve.pack``, ``serve.forward`` and
+        ``serve.complete``."""
+        with _span("serve.dispatch", dispatch=stats.dispatches) as span:
+            t_disp = time.perf_counter()
+            with _span("serve.pop"):
+                shape, batch = sched.next_dispatch()
+            span.set_metadata(lanes=len(batch))
+            with _span("serve.pack"):
+                frames, pidx = self._pack(batch, shape)
+            with _span("serve.forward"):
+                self.forward_slot(frames, len(batch), pidx)
+            if mode == "wallclock":
+                if extra_s > 0:
+                    time.sleep(extra_s)
+                t_pop = t_disp - t_start
+                now = time.perf_counter() - t_start
+                dt = time.perf_counter() - t_disp
+            else:
+                t_pop = now
+                dt = service_time_s + extra_s
+                now = now + dt
+            with _span("serve.complete"):
+                sched.complete(batch, now)
+                stats.record(shape, len(batch))
+                arrivals = [r.arrival for r in batch]
+                stats.record_wait(t_pop, arrivals)
+                latencies.extend(now - a for a in arrivals)
         return now, dt, shape
 
     def drain(self, sched, *, stats: Optional[ServeStats] = None,
@@ -630,13 +697,18 @@ class PolicyServer:
         while next_req < n or sched.pending:
             if mode == "wallclock":
                 now = time.perf_counter() - t_start
-            while next_req < n and trace[next_req].arrival <= now:
-                req = trace[next_req]
-                if admission is None:
-                    sched.admit(req)
-                else:
-                    admission.admit(req, now, sched, stats)
-                next_req = next_req + 1
+            if next_req < n and trace[next_req].arrival <= now:
+                with _span("serve.admit"):
+                    arrivals = []
+                    while next_req < n and trace[next_req].arrival <= now:
+                        req = trace[next_req]
+                        arrivals.append(req.arrival)
+                        if admission is None:
+                            sched.admit(req)
+                        else:
+                            admission.admit(req, now, sched, stats)
+                        next_req = next_req + 1
+                    stats.record_admit(now, arrivals)
             if next_req >= n and self.state == "serving":
                 self.state = "draining"   # only backlog left
             if not sched.pending:
@@ -647,7 +719,8 @@ class PolicyServer:
                 if mode == "wallclock":
                     wait = now - (time.perf_counter() - t_start)
                     if wait > 0:
-                        time.sleep(wait)
+                        with _span("serve.idle"):
+                            time.sleep(wait)
                 continue
             try_reloads(dispatch_idx)
             extra = (faults.dispatch_delay_s(dispatch_idx)

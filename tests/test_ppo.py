@@ -1,10 +1,12 @@
 """PPO: GAE correctness vs hand computation; learning on a trivial task."""
 import dataclasses
+import re
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.envs.api import Env, EnvSpec
 from repro.rl import ppo
@@ -125,3 +127,97 @@ def test_frame_stack_rollout_shapes():
     assert v_last.shape == (3,)
     # periodic reset happened (episode_len=5 < rollout_len=8)
     assert float(batch["done"].sum()) > 0
+
+
+# ---------------------------------------------------------------------------
+# Phase scopes of train_iteration
+# ---------------------------------------------------------------------------
+
+PHASES = ("ppo.noise", "ppo.rollout", "ppo.gae", "ppo.shuffle", "ppo.update")
+_WORK = ("fusion", "dot", "gather", "sort", "custom-call")
+
+
+def _phase(op_name):
+    """The innermost ``ppo.*`` component of an op name, or None."""
+    return next((p for p in reversed(op_name.split("/"))
+                 if p.startswith("ppo.")), None)
+
+
+def _compiled_ops(hlo):
+    """-> [(opcode, op_name or None, fused root's (opcode, op_name))] for
+    every op of the compiled module, and {computation: root op}."""
+    ops, roots, comp = [], {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"\s+(ROOT )?%?[^\s=]+ = .*?([a-z][\w-]*)\(", line)
+        if m is None:
+            c = re.match(r"(?:ENTRY )?%?([^\s(]+) \(", line)
+            comp = c.group(1) if c else comp
+            continue
+        name = re.search(r'op_name="((?:[^"\\]|\\.)*)"', line)
+        name = name.group(1) if name else None
+        calls = re.search(r"calls=%?([\w.\-]+)", line)
+        ops.append((m.group(2), name, calls.group(1) if calls else None))
+        if m.group(1):
+            roots[comp] = (m.group(2), name)
+    return ops, roots
+
+
+@pytest.fixture
+def no_compile_cache():
+    """The persistent cache's key leaves op names out, so an executable
+    cached before a scope existed would come back without it: compile
+    afresh."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("engine_route", [False, True])
+def test_train_iteration_ops_each_carry_one_phase(engine_route,
+                                                  no_compile_cache):
+    """The compiled ``train_iteration`` (the hoisted scan route, and the
+    engine's whole-horizon ``policy_rollout`` route) carries all five
+    phase scopes, and every fusion, dot, gather, sort and custom call
+    carries exactly one phase: the innermost ``ppo.*`` component of its
+    op name (a fusion's own, else its root's). The only ops with no op
+    name are ones XLA made itself, which hold no program work: a
+    broadcast of a constant, a rewritten reduction."""
+    from repro.core import engine, influence
+    from repro.launch.rl_train import build_domain
+    A, B, T = 4, 4, 8
+    gs, _, bls, stack = build_domain("traffic", 0, A)
+    acfg = influence.AIPConfig(kind="fnn", d_in=gs.spec.dset_dim,
+                               n_out=gs.spec.n_influence, hidden=16,
+                               stack=8)
+    aip = jax.vmap(lambda k: influence.init_aip(acfg, k))(
+        jax.random.split(jax.random.PRNGKey(0), A))
+    env = engine.make_unified_ials(bls, aip, acfg, n_agents=A,
+                                   use_horizon_kernel=engine_route)
+    assert (env.policy_rollout is not None) == engine_route
+    cfg = ppo.PPOConfig(obs_dim=gs.spec.obs_dim,
+                        n_actions=gs.spec.n_actions, frame_stack=stack,
+                        hidden=16, n_envs=B, rollout_len=T, episode_len=T,
+                        epochs=2, n_agents=A)
+    opt, iteration = ppo.make_train_iteration(env, cfg)
+    key = jax.random.PRNGKey(1)
+    params = ppo.init_policy(cfg, key)
+    args = (params, opt.init(params),
+            ppo.init_rollout_state(env, cfg, key), key)
+    ops, roots = _compiled_ops(iteration.lower(*args).compile().as_text())
+
+    seen = {_phase(n) for _, n, _ in ops if n}
+    assert set(PHASES) <= seen
+    for opcode, name, calls in ops:
+        if opcode not in _WORK:
+            continue
+        root_op, root_name = roots.get(calls, (None, None))
+        name = name or root_name
+        if name is None:
+            assert opcode == "fusion" and root_op in (
+                "broadcast", "reduce-window"), (opcode, root_op)
+            continue
+        assert _phase(name) in PHASES, (opcode, name)

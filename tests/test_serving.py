@@ -47,8 +47,8 @@ from repro.envs.warehouse import (WarehouseConfig,
                                   make_batched_local_warehouse_env)
 from repro.launch import policy_serve
 from repro.rl import ppo
-from repro.serving import (PolicyServer, Request, SlotScheduler,
-                           TraceConfig, synthetic_trace)
+from repro.serving import (PolicyServer, Request, ServeStats,
+                           SlotScheduler, TraceConfig, synthetic_trace)
 
 S = 8                                    # the test slot shape
 FRAME_STACK = {"traffic": 1, "warehouse": 8}    # as rl_train.build_domain
@@ -332,6 +332,83 @@ def test_virtual_replay_report_is_exact_and_deterministic():
     assert rep2.summary() == rep.summary()
     with pytest.raises(ValueError):
         srv.serve(trace, mode="closed-loop")
+
+
+def _dyadic_trace(srv, n=40):
+    """Arrivals on a 1/1024 s grid in bursts, so requests queue and every
+    clock sum of a virtual replay at a dyadic service time is exact."""
+    rng = np.random.default_rng(7)
+    arrivals = np.sort(rng.integers(0, 64, n)) / 1024
+    frame = np.zeros(srv.frame_dim, np.float32)
+    return [Request(rid=i, region=i % 5, klass=0, arrival=float(t),
+                    deadline=float(t) + 1.0, frame=frame)
+            for i, t in enumerate(arrivals)]
+
+
+def test_virtual_replay_wait_counters_add_up():
+    """Each request's latency is its queue wait (pop less arrival) plus
+    its service (completion less pop): summed over the replay the
+    counters and the report agree exactly. The loop notices an arrival
+    no later than its dispatch is popped."""
+    srv = _server("traffic", "auto")
+    trace = _dyadic_trace(srv)
+    svc = 2.0 ** -8
+    rep = srv.serve(trace, SlotScheduler(srv.slot), mode="virtual",
+                    service_time_s=svc)
+    st_ = rep.stats
+    assert rep.served == len(trace)
+    assert st_.queue_wait_s + rep.served * svc == sum(rep.latencies_s)
+    assert 0.0 < st_.admit_lag_s <= st_.queue_wait_s
+    assert 0.0 < st_.admit_lag_max_s <= st_.queue_wait_max_s
+    assert st_.queue_wait_max_s + svc == max(rep.latencies_s)
+    s = rep.summary()
+    assert (s["admit_lag_s"], s["admit_lag_max_s"], s["queue_wait_s"],
+            s["queue_wait_max_s"]) == (
+        st_.admit_lag_s, st_.admit_lag_max_s, st_.queue_wait_s,
+        st_.queue_wait_max_s)
+    fresh = ServeStats().summary()
+    for key in ("admit_lag_s", "admit_lag_max_s", "queue_wait_s",
+                "queue_wait_max_s", "padded_lane_frac", "rejected"):
+        assert fresh[key] == 0
+
+
+def test_serve_spans_nest_in_each_dispatch(tmp_path):
+    """A profiler trace of a replay holds one ``serve.dispatch`` span per
+    dispatch (its index and real lanes attached) with ``serve.pop``,
+    ``serve.pack``, ``serve.forward`` and ``serve.complete`` inside it in
+    that order, and ``serve.put``, ``serve.launch``, ``serve.wait``
+    inside ``serve.forward``."""
+    srv = _server("traffic", "auto")
+    trace = _dyadic_trace(srv)
+    with jax.profiler.trace(str(tmp_path)):
+        rep = srv.serve(trace, SlotScheduler(srv.slot), mode="virtual",
+                        service_time_s=2.0 ** -8)
+    spans = []
+    for path in tmp_path.rglob("*.xplane.pb"):
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                spans += [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                           dict(e.stats)) for e in line.events
+                          if e.name.startswith("serve.")]
+    spans.sort(key=lambda s: (s[0], -s[1]))
+
+    def inside(outer, names):
+        return [s[2] for s in spans if s[2] in names
+                and outer[0] <= s[0] and s[1] <= outer[1]]
+
+    disp = [s for s in spans if s[2] == "serve.dispatch"]
+    assert len(disp) == rep.dispatches > 1
+    assert [d[3]["dispatch"] for d in disp] == list(range(rep.dispatches))
+    assert sum(d[3]["lanes"] for d in disp) == rep.served
+    parts = ("serve.pop", "serve.pack", "serve.forward", "serve.complete")
+    for d in disp:
+        assert inside(d, parts) == list(parts)
+    fwd = [s for s in spans if s[2] == "serve.forward"]
+    assert len(fwd) == rep.dispatches
+    for f in fwd:
+        assert inside(f, ("serve.put", "serve.launch", "serve.wait")) == [
+            "serve.put", "serve.launch", "serve.wait"]
+    assert [s for s in spans if s[2] == "serve.admit"]
 
 
 # ------------------------------------------------------ restore + driver

@@ -15,6 +15,8 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the suite runs in several. The
 persistent compilation cache is off around these compiles (an entry
 compiled for a described chip cannot be read back without one)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -85,6 +87,19 @@ def _engine(domain, kind, A, mesh=None):
 _KEY = jax.ShapeDtypeStruct((2,), jnp.uint32)
 
 
+def _kernel_op_names(hlo):
+    """The ``op_name`` of every Pallas call in compiled HLO text."""
+    return [m.group(1) for line in hlo.splitlines()
+            if "tpu_custom_call" in line
+            for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+
+def _kernel_names(hlo):
+    """The kernels' fixed ``name``s: the scope just above each
+    ``pallas_call`` in its op name."""
+    return {n.split("/")[-2] for n in _kernel_op_names(hlo)}
+
+
 @pytest.mark.parametrize("domain,kind,A", [
     ("traffic", "fnn", 1), ("traffic", "gru", 25),
     ("warehouse", "gru", 36), ("warehouse", "fnn", 1)])
@@ -100,6 +115,7 @@ def test_engine_rollout_kernel_compiles(domain, kind, A, one_chip,
     hlo = jax.jit(env.rollout).lower(
         *_on((state, acts, keys), one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo
+    assert _kernel_names(hlo) == {"aip_rollout"}
 
 
 @pytest.mark.parametrize("domain,kind,A", [
@@ -117,6 +133,8 @@ def test_policy_rollout_kernel_compiles(domain, kind, A, one_chip,
     fn = jax.jit(lambda p, r, k: ppo.rollout(env, pcfg, p, r, k))
     hlo = fn.lower(*_on((pol, rs, _KEY), one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo
+    assert _kernel_names(hlo) == {"policy_rollout"}
+    assert all("/ppo.rollout/" in n for n in _kernel_op_names(hlo))
 
 
 @pytest.mark.parametrize("domain", ["traffic", "warehouse"])
@@ -135,6 +153,7 @@ def test_serve_forward_compiles(domain, one_chip):
         interpret=False))
     hlo = fn.lower(*_on((frames, mask, pol), one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo
+    assert _kernel_names(hlo) == {"serve_forward"}
 
 
 @pytest.mark.parametrize("domain", ["traffic", "warehouse"])
@@ -156,6 +175,7 @@ def test_serve_forward_multi_compiles(domain, one_chip):
     hlo = fn.lower(*_on((frames, mask, pidx, pols),
                         one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo
+    assert _kernel_names(hlo) == {"serve_forward_multi"}
 
 
 @pytest.mark.parametrize("domain,A", [("traffic", 1), ("traffic", 25),
@@ -172,6 +192,7 @@ def test_aip_step_compiles(domain, A, one_chip):
     fn = jax.jit(lambda *a: aip_step(*a, interpret=False))
     hlo = fn.lower(*_on(args, one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo
+    assert _kernel_names(hlo) == {"aip_step"}
 
 
 def _defs(hlo):
