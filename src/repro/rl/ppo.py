@@ -33,20 +33,24 @@ ARCHITECTURE §4). The scan paths themselves are fully bit-identical;
 preserves the keyed per-tick derivation exactly.
 
 Learner side: GAE is a log-depth ``lax.associative_scan`` over the affine
-recurrence (not a T-step sequential scan), minibatch epochs do ONE
-permutation gather per epoch and stream contiguous slices through the
-update scan (no per-minibatch gather copies), and ``train_iteration``
-donates its (params, opt_state, rollout-state) arguments so each PPO
-iteration updates in place instead of round-tripping fresh buffers.
+recurrence (not a T-step sequential scan); each minibatch epoch gathers
+one packed f32 record per sample — the action (as an exact f32 value),
+logp, advantage and return, with the frames too where they fit one
+128-lane tile beside them — under one permutation, so a gather's
+per-index cost is paid once rather than once per field, and streams
+contiguous slices through the update scan (no per-minibatch gather
+copies); ``train_iteration`` donates its (params, opt_state,
+rollout-state) arguments so each PPO iteration updates in place instead
+of round-tripping fresh buffers.
 
 Phases: every op of an iteration runs under one ``jax.named_scope``,
 which reaches the compiled ops' ``op_name`` (the innermost one counts)
 and so names each op's phase in a device trace — ``ppo.noise`` (key
 splits, the pre-drawn Gumbel, env and reset noise), ``ppo.rollout`` (the
 acting loop and the bootstrap value, whatever implements it),
-``ppo.gae`` (GAE and the flatten), ``ppo.shuffle`` (each epoch's
-permutation and gathers) and ``ppo.update`` (the minibatch gradient
-steps and the epoch loop around them).
+``ppo.gae`` (GAE and the flatten), ``ppo.shuffle`` (the record build,
+each epoch's permutation and gather) and ``ppo.update`` (the minibatch
+gradient steps and the epoch loop around them).
 """
 from __future__ import annotations
 
@@ -63,6 +67,8 @@ from repro.envs.api import BatchedEnv, Env, as_batched, horizon_noise
 from repro.nn.act import fast_tanh
 from repro.nn.module import dense_init, dense
 from repro.optim.adamw import adamw
+
+_LANES = 128    # one TPU lane tile: the widest minibatch record
 
 
 @dataclass(frozen=True)
@@ -437,35 +443,64 @@ def learner_update_fn(cfg: PPOConfig, opt):
     stale policy version is importance-corrected (and clipped) for free —
     that, plus the fleet's ``max_staleness`` drop policy, is the
     off-policy correction story (documented in ARCHITECTURE's
-    fault-tolerance contract)."""
+    fault-tolerance contract).
+
+    The flattened samples are packed once per iteration into one f32
+    record per sample, ``[a | logp | adv | ret]`` with the action stored
+    as its exact f32 value (not a bitcast), preceded by the policy input
+    ``x`` and zero-padded to one 128-lane tile when ``x`` fits the tile
+    beside the four scalars. Each epoch draws one permutation and gathers
+    the record with it, once (a wider ``x`` is gathered on its own beside
+    the 4-wide record): a gather pays its cost per index, not per byte
+    of the row, so one gather replaces one per field. The minibatches
+    ``ppo_loss`` sees are bitwise those of per-field gathers under the
+    same permutation, ``a`` still int32."""
 
     def learner_update(params, opt_state, batch, v_last, key):
         with jax.named_scope("ppo.gae"):
             adv, ret = gae(batch, v_last, cfg.gamma, cfg.lam)
             total = batch["a"].size      # T * n_envs * n_agents samples
-            flat = {
-                "x": batch["x"].reshape(total, -1),
-                "a": batch["a"].reshape(total),
-                "logp": batch["logp"].reshape(total),
-                "adv": adv.reshape(total),
-                "ret": ret.reshape(total),
-            }
+            x = batch["x"].reshape(total, -1)
+        F = x.shape[1]
+        with jax.named_scope("ppo.shuffle"):
+            # an exact f32 action, not a bitcast int: that would be a
+            # denormal, which a TPU fusion may flush. The zero pad fills
+            # the lane tile, else XLA lays a narrow record out
+            # samples-minor and each row becomes a strided read. Frames
+            # wider than the tile stay out: inside an f32 record they cost
+            # the update more relayouts than the saved gather.
+            cols = [c.reshape(total, 1).astype(jnp.float32)
+                    for c in (batch["a"], batch["logp"], adv, ret)]
+            packed = F + len(cols) <= _LANES
+            if packed:
+                pad = jnp.zeros((total, _LANES - F - len(cols)), jnp.float32)
+                rows = [jnp.concatenate([x] + cols + [pad], axis=1)]
+            else:
+                rows = [x, jnp.concatenate(cols, axis=1)]
+        s = F if packed else 0           # first scalar column of a record
         n_mb = cfg.n_minibatches
         mb_size = total // n_mb
 
         def epoch(carry, k):
             params, opt_state = carry
-            # ONE permutation gather per epoch; the scan then streams
-            # contiguous (mb_size, ...) slices — no per-minibatch gather
-            # copies (same minibatch contents as gathering row-by-row)
+            # ONE gather of each record per epoch; the scan then streams
+            # contiguous (mb_size, width) slices — no per-minibatch
+            # gather copies (same minibatch contents as gathering
+            # row-by-row)
             with jax.named_scope("ppo.shuffle"):
                 perm = jax.random.permutation(k, total)[:n_mb * mb_size]
-                shuf = jax.tree_util.tree_map(
-                    lambda v: v[perm].reshape((n_mb, mb_size)
-                                              + v.shape[1:]), flat)
+                shuf = [v.at[perm].get(
+                    mode="promise_in_bounds", unique_indices=True,
+                    wrap_negative_indices=False).reshape((n_mb, mb_size, -1))
+                    for v in rows]
 
-            def mb_step(carry, mb):
+            def mb_step(carry, mb_rows):
                 params, opt_state = carry
+                r = mb_rows[-1]
+                mb = {"x": r[:, :F] if packed else mb_rows[0],
+                      "a": r[:, s].astype(jnp.int32),
+                      "logp": r[:, s + 1], "adv": r[:, s + 2],
+                      "ret": r[:, s + 3]}
                 (l, m), g = jax.value_and_grad(ppo_loss, has_aux=True)(
                     params, cfg, mb)
                 params, opt_state, _ = opt.update(g, opt_state, params)

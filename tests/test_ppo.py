@@ -1,5 +1,6 @@
 """PPO: GAE correctness vs hand computation; learning on a trivial task."""
 import dataclasses
+import functools
 import re
 from typing import NamedTuple
 
@@ -176,16 +177,11 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("engine_route", [False, True])
-def test_train_iteration_ops_each_carry_one_phase(engine_route,
-                                                  no_compile_cache):
-    """The compiled ``train_iteration`` (the hoisted scan route, and the
-    engine's whole-horizon ``policy_rollout`` route) carries all five
-    phase scopes, and every fusion, dot, gather, sort and custom call
-    carries exactly one phase: the innermost ``ppo.*`` component of its
-    op name (a fusion's own, else its root's). The only ops with no op
-    name are ones XLA made itself, which hold no program work: a
-    broadcast of a constant, a rewritten reduction."""
+@functools.lru_cache(maxsize=None)
+def _iteration_hlo(engine_route):
+    """The compiled HLO text of a small traffic ``train_iteration`` on
+    the hoisted scan route or the engine's ``policy_rollout`` route.
+    Compile with the ``no_compile_cache`` fixture active."""
     from repro.core import engine, influence
     from repro.launch.rl_train import build_domain
     A, B, T = 4, 4, 8
@@ -207,7 +203,20 @@ def test_train_iteration_ops_each_carry_one_phase(engine_route,
     params = ppo.init_policy(cfg, key)
     args = (params, opt.init(params),
             ppo.init_rollout_state(env, cfg, key), key)
-    ops, roots = _compiled_ops(iteration.lower(*args).compile().as_text())
+    return iteration.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("engine_route", [False, True])
+def test_train_iteration_ops_each_carry_one_phase(engine_route,
+                                                  no_compile_cache):
+    """The compiled ``train_iteration`` (the hoisted scan route, and the
+    engine's whole-horizon ``policy_rollout`` route) carries all five
+    phase scopes, and every fusion, dot, gather, sort and custom call
+    carries exactly one phase: the innermost ``ppo.*`` component of its
+    op name (a fusion's own, else its root's). The only ops with no op
+    name are ones XLA made itself, which hold no program work: a
+    broadcast of a constant, a rewritten reduction."""
+    ops, roots = _compiled_ops(_iteration_hlo(engine_route))
 
     seen = {_phase(n) for _, n, _ in ops if n}
     assert set(PHASES) <= seen
@@ -221,3 +230,152 @@ def test_train_iteration_ops_each_carry_one_phase(engine_route,
                 "broadcast", "reduce-window"), (opcode, root_op)
             continue
         assert _phase(name) in PHASES, (opcode, name)
+
+
+@pytest.mark.parametrize("engine_route", [False, True])
+def test_shuffle_is_one_gather_per_epoch(engine_route, no_compile_cache):
+    """The shuffle gathers one packed per-sample record inside the epoch
+    loop: exactly one ``gather`` of the compiled ``train_iteration`` has
+    ``ppo.shuffle`` as its innermost phase, and it sits in the body of
+    the update's epoch loop, not once per field."""
+    ops, _ = _compiled_ops(_iteration_hlo(engine_route))
+    shuffle = [n for op, n, _ in ops
+               if op == "gather" and n and _phase(n) == "ppo.shuffle"]
+    assert len(shuffle) == 1, shuffle
+    assert "ppo.update/while/body/" in shuffle[0], shuffle[0]
+
+
+def _shuffle_gathers(hlo):
+    """-> [row width] of each ``gather`` whose phase is ppo.shuffle."""
+    widths = []
+    for line in hlo.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if (" gather(" in line and name
+                and _phase(name.group(1)) == "ppo.shuffle"):
+            widths.append(int(re.search(r"slice_sizes=\{1,(\d+)\}",
+                                        line).group(1)))
+    return widths
+
+
+@pytest.mark.parametrize("obs_dim,stack,want", [
+    (41, 1, [128]),         # traffic's 41-wide frames
+    (37, 8, [4, 296]),      # warehouse's 296-wide frames
+], ids=["narrow", "wide"])
+def test_shuffle_record_width(obs_dim, stack, want, no_compile_cache):
+    """Frames that fit one lane tile beside the four scalars ride in
+    the record, zero-padded to 128 lanes: one gather per epoch. Wider
+    frames are gathered on their own beside a 4-wide scalar record."""
+    cfg = ppo.PPOConfig(obs_dim=obs_dim, n_actions=5, frame_stack=stack,
+                        hidden=16, n_envs=8, rollout_len=8, episode_len=8,
+                        epochs=2, n_minibatches=1)
+    opt = ppo.make_optimizer(cfg)
+    params = ppo.init_policy(cfg, jax.random.PRNGKey(0))
+    lead = (cfg.rollout_len, cfg.n_envs)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    batch = {"x": f32(*lead, obs_dim * stack),
+             "a": jax.ShapeDtypeStruct(lead, jnp.int32), "logp": f32(*lead),
+             "v": f32(*lead), "r": f32(*lead), "done": f32(*lead)}
+    hlo = jax.jit(ppo.learner_update_fn(cfg, opt)).lower(
+        params, opt.init(params), batch, f32(cfg.n_envs),
+        jax.random.PRNGKey(1)).compile().as_text()
+    assert sorted(_shuffle_gathers(hlo)) == want
+
+
+# ---------------------------------------------------------------------------
+# The packed-record shuffle against the per-field gathers it replaced
+# ---------------------------------------------------------------------------
+
+def _five_gather_learner(cfg, opt):
+    """Oracle: the learner with one permutation gather per field."""
+    def learner_update(params, opt_state, batch, v_last, key):
+        adv, ret = ppo.gae(batch, v_last, cfg.gamma, cfg.lam)
+        total = batch["a"].size
+        flat = {"x": batch["x"].reshape(total, -1),
+                "a": batch["a"].reshape(total),
+                "logp": batch["logp"].reshape(total),
+                "adv": adv.reshape(total), "ret": ret.reshape(total)}
+        n_mb = cfg.n_minibatches
+        mb_size = total // n_mb
+
+        def epoch(carry, k):
+            perm = jax.random.permutation(k, total)[:n_mb * mb_size]
+            shuf = jax.tree_util.tree_map(
+                lambda v: v[perm].reshape((n_mb, mb_size) + v.shape[1:]),
+                flat)
+
+            def mb_step(carry, mb):
+                params, opt_state = carry
+                (l, _), g = jax.value_and_grad(ppo.ppo_loss, has_aux=True)(
+                    params, cfg, mb)
+                params, opt_state, _ = opt.update(g, opt_state, params)
+                return (params, opt_state), l
+
+            carry, ls = jax.lax.scan(mb_step, carry, shuf)
+            return carry, ls.mean()
+
+        (params, opt_state), losses = jax.lax.scan(
+            epoch, (params, opt_state), jax.random.split(key, cfg.epochs))
+        return params, opt_state, losses.mean()
+
+    return learner_update
+
+
+@pytest.mark.parametrize("n_agents,n_actions,n_envs,T,obs_dim", [
+    (5, 2, 3, 8, 3),     # multi-agent, traffic's two actions
+    (1, 5, 5, 7, 3),     # single agent; 35 samples leave 3 unused
+    (4, 5, 2, 8, 40),    # frames wider than a lane tile: no packing
+], ids=["agents5", "single", "wide"])
+def test_packed_shuffle_matches_per_field_gathers(monkeypatch, n_agents,
+                                                  n_actions, n_envs, T,
+                                                  obs_dim):
+    """The learner's packed-record epochs feed ``ppo_loss`` the same
+    minibatches, field by field and bit for bit (``a`` still int32), as
+    five per-field gathers under the same permutation, and end on the
+    same params and Adam state."""
+    cfg = ppo.PPOConfig(obs_dim=obs_dim, n_actions=n_actions, frame_stack=4,
+                        hidden=16, n_envs=n_envs, rollout_len=T,
+                        episode_len=T, epochs=3, n_minibatches=4,
+                        n_agents=n_agents)
+    lead = (T, n_envs) + cfg.agent_shape
+    ks = jax.random.split(jax.random.PRNGKey(3), 8)
+    batch = {
+        "x": jax.random.normal(ks[0], lead + (cfg.obs_dim * cfg.frame_stack,)),
+        "a": jax.random.randint(ks[1], lead, 0, n_actions, jnp.int32),
+        "logp": -jax.random.uniform(ks[2], lead, minval=0.1, maxval=2.0),
+        "v": jax.random.normal(ks[3], lead),
+        "r": jax.random.normal(ks[4], lead),
+        "done": (jax.random.uniform(ks[5], lead) < 0.2).astype(jnp.float32),
+    }
+    v_last = jax.random.normal(ks[6], lead[1:])
+    params = ppo.init_policy(cfg, ks[7])
+    opt = ppo.make_optimizer(cfg)
+    key = jax.random.PRNGKey(4)
+
+    seen = {"packed": [], "oracle": []}
+    loss = ppo.ppo_loss
+
+    def run(make, tag):
+        def spy(params, cfg, mb):
+            jax.debug.callback(lambda mb: seen[tag].append(mb), mb,
+                               ordered=True)
+            return loss(params, cfg, mb)
+        monkeypatch.setattr(ppo, "ppo_loss", spy)
+        upd = jax.jit(make(cfg, opt))
+        out = upd(params, opt.init(params), batch, v_last, key)
+        jax.effects_barrier()
+        return out
+
+    p1, o1, _ = run(ppo.learner_update_fn, "packed")
+    p0, o0, _ = run(_five_gather_learner, "oracle")
+
+    assert len(seen["packed"]) == len(seen["oracle"]) == (
+        cfg.epochs * cfg.n_minibatches)
+    for mb1, mb0 in zip(seen["packed"], seen["oracle"]):
+        assert mb1.keys() == mb0.keys()
+        assert mb1["a"].dtype == np.int32
+        for k in mb0:
+            assert mb1[k].dtype == mb0[k].dtype, k
+            np.testing.assert_array_equal(mb1[k], mb0[k], err_msg=k)
+    for got, want in zip(jax.tree_util.tree_leaves((p1, o1)),
+                         jax.tree_util.tree_leaves((p0, o0))):
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
