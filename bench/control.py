@@ -51,8 +51,8 @@ def _train_rows(cell, cfg, mix, seeds, devices, fault_seeds):
         return part
 
     def rotated_policy(lanes):
-        def fn(p, x, dt):
-            logits, v = policy(p, x, dt)
+        def fn(cfg_, p, x, dt):
+            logits, v = policy(cfg_, p, x, dt)
             if x.ndim == 3:                  # the actor, (B, A, S)
                 logits = logits.at[lanes].set(
                     jnp.roll(logits[lanes], 1, axis=-1) + 1.0)

@@ -1,18 +1,33 @@
 """Find a cell's configuration, traffic mix, limits and metric readers by
-name. Everything is data under ``bench/``:
+name. Everything is data or a module of its own under ``bench/``:
 
 - ``BENCHMARK.json`` (repo root): cells (``workloads``) and metrics;
-- ``bench/configs/<config>.json``: a configuration's sizes;
+- ``bench/configs/<config>.json``: a configuration's sizes, each of
+  which reaches the program (``bench/lib/train.py::Program``);
 - ``bench/mixes/<traffic>.json``: a traffic mix's parameters;
 - ``bench/limits/<cell>.json``: the limits ``correct`` is held to;
 - ``bench/metrics/<metric>.py``: a per-layer metric's reader, a module
-  with ``read(run) -> float | None``.
+  with ``read(run) -> float | None``;
+- ``bench/reference/aip/<kind>.py``, ``bench/reference/policy/<kind>.py``:
+  an AIP backbone or a policy network, as the configuration's
+  ``aip.kind`` or ``policy.kind`` names it: its weights, its plain
+  reference and its operation and word counts;
+- ``bench/lib/domains/<domain>.py`` and ``bench/reference/<domain>.py``:
+  a domain's simulators as the program builds them, and its plain
+  reference.
 
-A new cell, configuration, mix or metric is a new file and a new entry;
-no existing file changes.
+A new cell, configuration, mix, metric or AIP backbone is a new file and
+a new entry; no existing file changes. So a configuration with a new
+backbone brings its config, its mix, its limits, any metric readers it
+needs, and ``bench/reference/aip/<kind>.py``. A new policy's module is a
+new file too, but the program has one policy network, ``ppo``'s shared
+MLP, and takes no kind: until it builds another, and a ``benchmark`` PR
+passes the kind on in ``train.Program`` and ``serve.Server``, set-up stops
+there, naming ``policy.kind`` (``weights.check_policy``).
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 from pathlib import Path
@@ -66,3 +81,18 @@ def reader(metric: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def find(package: str, kind: str):
+    """-> the module ``<package>.<kind>``: one file per kind, so a new kind
+    is a new file. ValueError, naming the kind and the directory, where
+    there is none."""
+    name = f"{package}.{kind}"
+    if kind.isidentifier():
+        try:
+            return importlib.import_module(name)
+        except ModuleNotFoundError as e:
+            if e.name != name:
+                raise
+    where = importlib.import_module(package).__path__[0]
+    raise ValueError(f"unknown kind {kind!r}: no module {kind}.py in {where}")

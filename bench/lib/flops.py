@@ -10,26 +10,19 @@ share.
 """
 from __future__ import annotations
 
+from bench.reference import aip, policy
+
 WORD = 4          # every kernel operand is f32, int32 or uint32
 
 
 def policy_flops(cfg: dict) -> int:
-    """One actor-critic forward: S->H->H->(n_actions + 1)."""
-    p = cfg["policy"]
-    S = cfg["obs_dim"] * p["frame_stack"]
-    H = p["hidden"]
-    return 2 * (S * H + H * H + H * (cfg["n_actions"] + 1))
+    """One policy forward, by its ``policy.kind`` module."""
+    return policy.module(cfg).flops(cfg)
 
 
 def aip_flops(cfg: dict) -> int:
-    """One AIP cell: FNN stack*d->K->K->M, or GRU (d + H)->3H, H->M."""
-    a, d, M = cfg["aip"], cfg["dset_dim"], cfg["n_influence"]
-    K = a["hidden"]
-    if a["kind"] == "fnn":
-        return 2 * (a["stack"] * d * K + K * K + K * M)
-    if a["kind"] == "gru":
-        return 2 * (d * 3 * K + K * 3 * K + K * M)
-    raise ValueError(f"unknown AIP kind {a['kind']!r}")
+    """One AIP cell, by its ``aip.kind`` module."""
+    return aip.module(cfg).flops(cfg)
 
 
 def model_flops_per_sample(cfg: dict) -> int:
@@ -41,24 +34,15 @@ def model_flops_per_sample(cfg: dict) -> int:
 
 
 def _aip_state_words(cfg: dict) -> int:
-    a = cfg["aip"]
-    return a["stack"] * cfg["dset_dim"] if a["kind"] == "fnn" \
-        else a["hidden"]
+    return aip.module(cfg).state_words(cfg)
 
 
 def aip_weight_words(cfg: dict) -> int:
-    a, d, M = cfg["aip"], cfg["dset_dim"], cfg["n_influence"]
-    K = a["hidden"]
-    if a["kind"] == "fnn":
-        return a["stack"] * d * K + K + K * K + K + K * M + M
-    return d * 3 * K + K * 3 * K + 3 * K + K * M + M
+    return aip.module(cfg).weight_words(cfg)
 
 
 def policy_weight_words(cfg: dict) -> int:
-    p = cfg["policy"]
-    S, H = cfg["obs_dim"] * p["frame_stack"], p["hidden"]
-    n = cfg["n_actions"] + 1
-    return S * H + H + H * H + H + H * n + n
+    return policy.module(cfg).weight_words(cfg)
 
 
 def rollout_kernel_cost(cfg: dict, lanes: int, T: int,

@@ -48,8 +48,16 @@ class Server:
     """The policy server under test, with the benchmark's recording."""
 
     def __init__(self, cfg: dict, mix: dict, seed: int):
+        from repro.rl import ppo
         from repro.serving import PolicyServer
         self.cfg, self.mix = cfg, mix
+        pol = cfg["policy"]
+        pcfg = ppo.PPOConfig(
+            obs_dim=cfg["obs_dim"], n_actions=cfg["n_actions"],
+            frame_stack=pol["frame_stack"], hidden=pol["hidden"],
+            fast_gates=pol["fast_gates"])
+        # the server serves checkpoints of ppo's one policy network
+        weights.check_policy(cfg, lambda k: ppo.init_policy(pcfg, k))
         self.params = weights.make(cfg, seed)["policy"]
         self.server = PolicyServer(
             self.params, obs_dim=cfg["obs_dim"], n_actions=cfg["n_actions"],
@@ -137,5 +145,5 @@ def reference_logits(cfg: dict, params, frames: np.ndarray, dt):
     import jax
     import jax.numpy as jnp
     from bench.reference import common
-    fwd = jax.jit(lambda p, x: common.policy(p, x, dt)[0])
+    fwd = jax.jit(lambda p, x: common.policy(cfg, p, x, dt)[0])
     return np.asarray(fwd(params, jnp.asarray(frames)), np.float32)

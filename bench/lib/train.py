@@ -1,8 +1,9 @@
 """The training cells: rl_train's integrated PPO loop on the IALS.
 
 Set-up builds one object, ``ppo.make_train_iteration``'s jitted
-``train_iteration`` over the unified engine that ``rl_train`` builds, with
-the AIP and policy weights made from the seed, and drives it through its
+``train_iteration`` over the unified engine as ``rl_train`` builds it for
+its IALS simulator, at the configuration's widths, with the AIP and
+policy weights made from the seed, and drives it through its
 first three iterations. Those compile it, warm it, and are what the
 reference follows. The same object and state then run the measured
 window, threaded as ``rl_train._run_integrated`` threads them: donated
@@ -26,59 +27,97 @@ CHECKED = 3         # iterations the reference follows
 
 class Program:
     """The system under test for one configuration and mix: built once,
-    then started from any seed."""
+    then started from any seed. Every width the configuration states
+    reaches the program: the grid and agent count the simulators are
+    built at, the policy's hidden width and frame stack, and the AIP's
+    ``aip`` block less its weights' seed and head bias. What the program
+    cannot take is checked against it, naming the key: the frame stack
+    ``rl_train`` trains the domain at, and the parameters of the one
+    policy network it has against those ``policy.kind`` builds."""
 
     def __init__(self, cfg: dict, mix: dict, devices):
-        from repro.launch import rl_train
+        from repro.core import influence
         from repro.launch.mesh import make_host_mesh
         from repro.rl import ppo
+        from repro.launch import rl_train
+        from bench.lib import domains
         self.cfg, self.mix = cfg, mix
         A, B, pc = cfg["n_agents"], mix["n_envs"], cfg["ppo"]
+        if A < 2:
+            raise ValueError(f"{cfg['name']}: n_agents {A}: rl_train builds "
+                             "a single-agent simulator for one agent, "
+                             "bench/lib/domains only multi-agent ones")
         self.mesh = (make_host_mesh(n_devices=len(devices), devices=devices)
                      if len(devices) > 1 else None)
-        gs, _, bls, stack = rl_train.build_domain(cfg["domain"], 0, A)
+        gs, self.bls = domains.module(cfg).build(cfg)
         spec = gs.spec
-        got = (spec.obs_dim, spec.dset_dim, spec.n_influence,
-               spec.n_actions, stack)
-        want = (cfg["obs_dim"], cfg["dset_dim"], cfg["n_influence"],
-                cfg["n_actions"], cfg["policy"]["frame_stack"])
-        if got != want:
-            raise ValueError(f"{cfg['name']}: the program builds "
-                             f"{got}, the configuration states {want}")
+        got = {"n_agents": spec.n_agents, "obs_dim": spec.obs_dim,
+               "dset_dim": spec.dset_dim, "n_influence": spec.n_influence,
+               "n_actions": spec.n_actions,
+               # rl_train trains each domain at one frame stack
+               "policy.frame_stack": rl_train.build_domain(cfg["domain"])[3]}
+        want = {**{k: cfg[k] for k in got if k in cfg},
+                "policy.frame_stack": cfg["policy"]["frame_stack"]}
+        bad = {k: (v, want[k]) for k, v in got.items() if v != want[k]}
+        if bad:
+            raise ValueError(f"{cfg['name']}: the program builds, and the "
+                             f"configuration states, {bad}")
         self.pcfg = ppo.PPOConfig(
             obs_dim=spec.obs_dim, n_actions=spec.n_actions,
-            frame_stack=stack, hidden=cfg["policy"]["hidden"], n_envs=B,
+            frame_stack=cfg["policy"]["frame_stack"],
+            hidden=cfg["policy"]["hidden"], n_envs=B,
             rollout_len=pc["rollout_len"], episode_len=pc["episode_len"],
             gamma=pc["gamma"], lam=pc["lam"], clip=pc["clip"],
             entropy_coef=pc["entropy_coef"], value_coef=pc["value_coef"],
             lr=pc["lr"], epochs=pc["epochs"],
             n_minibatches=pc["n_minibatches"], n_agents=A,
             fast_gates=cfg["policy"]["fast_gates"])
-        self.sim = rl_train.prepare_simulator(
-            "ials", gs, bls, cfg["aip"]["kind"], collect_episodes=0,
-            ep_len=pc["episode_len"], aip_epochs=0, mesh=self.mesh)
+        weights.check_policy(cfg, lambda k: ppo.init_policy(self.pcfg, k))
+        widths = {k: v for k, v in cfg["aip"].items()
+                  if k not in ("weights_seed", "head_bias")}
+        self.acfg = influence.AIPConfig(
+            d_in=spec.dset_dim, n_out=spec.n_influence, **widths)
         self.samples_per_iteration = A * B * pc["rollout_len"]
         self.iteration = None
 
     def start(self, seed: int):
         """-> (params, opt_state, rollout_state) for ``seed``; builds the
-        engine and the jitted iteration on first use (the AIP weights are
-        the configuration's, the same for every seed)."""
+        engine (as ``rl_train``'s IALS simulator builds it) and the jitted
+        iteration on first use (the AIP weights are the configuration's,
+        the same for every seed)."""
+        from repro.core import engine
         from repro.rl import ppo
         w = weights.make(self.cfg, seed)
-        if self.iteration is None:
+        first = self.iteration is None
+        if first:
             self.aip = w["aip"]
-            env = self.sim.make_env(w["aip"])
-            self.env = env
+            self.env = engine.make_unified_ials(
+                self.bls, w["aip"], self.acfg,
+                n_agents=self.cfg["n_agents"], mesh=self.mesh)
             self.opt, self.iteration = ppo.make_train_iteration(
-                env, self.pcfg, mesh=self.mesh)
+                self.env, self.pcfg, mesh=self.mesh)
         params = w["policy"]
         ost = self.opt.init(params)
         rs = ppo.init_rollout_state(
             self.env, self.pcfg, weights.stream(seed, weights.K_ROLLOUT),
             mesh=self.mesh)
+        if first:
+            self._check_aip_state(rs.env_state.aip_state)
         params, ost = ppo.replicate((params, ost), self.mesh)
         return params, ost, rs
+
+    def _check_aip_state(self, state):
+        """The engine's AIP state against the one the configuration's
+        ``aip`` block gives its backbone."""
+        import jax
+        from bench.reference import aip
+        cfg = self.cfg
+        want = jax.eval_shape(lambda: aip.module(cfg).zero(
+            cfg, self.mix["n_envs"], cfg["n_agents"])).shape
+        if state.shape != want:
+            raise ValueError(f"{cfg['name']}: the program's AIP state is "
+                             f"{state.shape}, the configuration's aip block "
+                             f"{cfg['aip']} gives {want}")
 
 
 def iteration_keys(seed: int, start: int, n: int) -> np.ndarray:

@@ -26,49 +26,17 @@ def stream(seed: int, tag: int):
     return jax.random.fold_in(root_key(seed), tag)
 
 
-def _dense(key, d_in, d_out, scale=None, lead=()):
-    import jax
-    import jax.numpy as jnp
-    scale = d_in ** -0.5 if scale is None else scale
-    return {"w": jax.random.truncated_normal(
-                key, -2.0, 2.0, lead + (d_in, d_out)) * scale,
-            "b": jnp.zeros(lead + (d_out,), jnp.float32)}
-
-
-def _policy(cfg, key):
-    import jax
-    p = cfg["policy"]
-    S, H = cfg["obs_dim"] * p["frame_stack"], p["hidden"]
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    return {"l1": _dense(k1, S, H), "l2": _dense(k2, H, H),
-            "pi": _dense(k3, H, cfg["n_actions"], scale=0.01),
-            "v": _dense(k4, H, 1, scale=0.1)}
-
-
-def _aip(cfg, key):
-    """Per-agent AIP weights, (A, ...) stacked, in the engine's layout."""
-    import jax
-    a, d, M = cfg["aip"], cfg["dset_dim"], cfg["n_influence"]
-    K, lead = a["hidden"], (cfg["n_agents"],)
-    k1, k2, k3 = jax.random.split(key, 3)
-    head = _dense(k3, K, M, lead=lead)
-    head["b"] = head["b"] + a["head_bias"]
-    if a["kind"] == "fnn":
-        return {"l1": _dense(k1, a["stack"] * d, K, lead=lead),
-                "l2": _dense(k2, K, K, lead=lead), "head": head}
-    wx = _dense(k1, d, 3 * K, lead=lead)
-    wh = _dense(k2, K, 3 * K, lead=lead)
-    return {"gru": {"wx": wx["w"], "wh": wh["w"], "b": wx["b"]},
-            "head": head}
-
-
 @functools.lru_cache(maxsize=8)
 def _maker(cfg_json: str):
+    """The jitted maker of a configuration's weights, each network's by
+    the module of its kind."""
     import json
     import jax
+    from bench.reference import aip, policy
     cfg = json.loads(cfg_json)
-    return jax.jit(lambda kp, ka: {"policy": _policy(cfg, kp),
-                                   "aip": _aip(cfg, ka)})
+    pol, net = policy.module(cfg), aip.module(cfg)
+    return jax.jit(lambda kp, ka: {"policy": pol.init(cfg, kp),
+                                   "aip": net.init(cfg, ka)})
 
 
 def make(cfg: dict, seed: int) -> dict:
@@ -78,3 +46,25 @@ def make(cfg: dict, seed: int) -> dict:
     import jax
     return _maker(json.dumps(cfg, sort_keys=True))(
         stream(seed, K_POLICY), jax.random.PRNGKey(cfg["aip"]["weights_seed"]))
+
+
+def check_policy(cfg: dict, program_init) -> None:
+    """Stop set-up, naming ``policy.kind``, where the parameters the
+    program's policy takes (``program_init(key)``) are not those that the
+    configuration's kind builds. The program has one policy network,
+    ``ppo``'s shared MLP, and takes no kind: a kind whose parameters
+    differ would run the MLP against another network's reference."""
+    import jax
+    from bench.reference import policy
+
+    def layout(init):
+        tree = jax.eval_shape(init, jax.random.PRNGKey(0))
+        return [(jax.tree_util.keystr(p), s.shape, str(s.dtype))
+                for p, s in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+    want = layout(lambda k: policy.module(cfg).init(cfg, k))
+    got = layout(program_init)
+    if got != want:
+        raise ValueError(
+            f"{cfg['name']}: policy.kind {policy.kind(cfg)!r} gives the "
+            f"parameters {want}, the program's policy takes {got}")

@@ -24,6 +24,9 @@ import importlib
 import jax
 import jax.numpy as jnp
 
+from bench.reference import aip as aips
+from bench.reference import policy as policies
+
 _CLAMP = 4.97178686
 
 
@@ -49,47 +52,32 @@ def domain(cfg):
     return importlib.import_module(f"bench.reference.{cfg['domain']}")
 
 
-def policy(p, x, dt):
-    """Actor-critic MLP, rational tanh hidden layers -> (logits, value)."""
-    c = lambda a: a.astype(dt)
-    h = tanh_r(jnp.dot(x.astype(dt), c(p["l1"]["w"])) + c(p["l1"]["b"]))
-    h = tanh_r(jnp.dot(h, c(p["l2"]["w"])) + c(p["l2"]["b"]))
-    logits = jnp.dot(h, c(p["pi"]["w"])) + c(p["pi"]["b"])
-    v = jnp.dot(h, c(p["v"]["w"]))[..., 0] + c(p["v"]["b"])[0]
-    return logits.astype(jnp.float32), v.astype(jnp.float32)
+def dense(key, d_in, d_out, scale=None, lead=()):
+    """A dense layer's weights: fan-in truncated normal, zero bias, with
+    ``lead`` axes in front (one layer per agent)."""
+    scale = d_in ** -0.5 if scale is None else scale
+    return {"w": jax.random.truncated_normal(
+                key, -2.0, 2.0, lead + (d_in, d_out)) * scale,
+            "b": jnp.zeros(lead + (d_out,), jnp.float32)}
 
 
-def _per_agent(x, w, dt):
+def per_agent(x, w, dt):
     """(B, A, i) x (A, i, j) -> (B, A, j): each agent its own weights."""
     return jnp.einsum("bai,aij->baj", x.astype(dt), w.astype(dt))
 
 
+def policy(cfg, p, x, dt):
+    """The configuration's policy network -> (logits, value)."""
+    return policies.module(cfg).forward(p, x, dt)
+
+
 def aip_step(cfg, w, s, d, dt):
     """One AIP tick per lane: -> (new state, influence logits (B, A, M))."""
-    if cfg["aip"]["kind"] == "fnn":
-        buf = jnp.concatenate([s[..., 1:, :], d[..., None, :]], axis=-2)
-        x = buf.reshape(buf.shape[:2] + (-1,))
-        h = jax.nn.relu(_per_agent(x, w["l1"]["w"], dt) + w["l1"]["b"].astype(dt))
-        h = jax.nn.relu(_per_agent(h, w["l2"]["w"], dt) + w["l2"]["b"].astype(dt))
-        lg = _per_agent(h, w["head"]["w"], dt) + w["head"]["b"].astype(dt)
-        return buf, lg.astype(jnp.float32)
-    H = s.shape[-1]
-    g = w["gru"]
-    gx = _per_agent(d, g["wx"], dt) + g["b"].astype(dt)
-    gh = _per_agent(s, g["wh"], dt)
-    r = sigmoid_r(gx[..., :H] + gh[..., :H])
-    z = sigmoid_r(gx[..., H:2 * H] + gh[..., H:2 * H])
-    n = tanh_r(gx[..., 2 * H:] + r * gh[..., 2 * H:])
-    h2 = ((1.0 - z) * n + z * s.astype(dt)).astype(jnp.float32)
-    lg = _per_agent(h2, w["head"]["w"], dt) + w["head"]["b"].astype(dt)
-    return h2, lg.astype(jnp.float32)
+    return aips.module(cfg).step(cfg, w, s, d, dt)
 
 
 def aip_zero(cfg, B, A):
-    a = cfg["aip"]
-    if a["kind"] == "fnn":
-        return jnp.zeros((B, A, a["stack"], cfg["dset_dim"]), jnp.float32)
-    return jnp.zeros((B, A, a["hidden"]), jnp.float32)
+    return aips.module(cfg).zero(cfg, B, A)
 
 
 def _split_lanes(tree, B, A):
@@ -138,7 +126,7 @@ def rollout(cfg, aip_w, pol, state, key, dt):
     def body(st, xs):
         g, b, n, rs, dn = xs
         x = st["frames"].reshape((B, A, -1))
-        logits, v = policy(pol, x, dt)
+        logits, v = policy(cfg, pol, x, dt)
         a = jnp.argmax(logits + g, axis=-1)
         d = dom.dset(st["ls"], a)
         s2, lg = aip_step(cfg, aip_w, st["aip"], d, dt)
@@ -162,7 +150,7 @@ def rollout(cfg, aip_w, pol, state, key, dt):
 
     st, batch = jax.lax.scan(body, state, (gum, bits, nz, resets, done))
     st["t"] = (state["t"] + T) % ep
-    _, v_last = policy(pol, st["frames"].reshape((B, A, -1)), dt)
+    _, v_last = policy(cfg, pol, st["frames"].reshape((B, A, -1)), dt)
     lsm = jax.nn.log_softmax(batch["logits"])
     batch["logp"] = jnp.take_along_axis(lsm, batch["a"][..., None], -1)[..., 0]
     return st, batch, v_last
@@ -187,7 +175,7 @@ def gae(batch, v_last, gamma, lam):
 
 def ppo_loss(cfg, pol, mb, dt):
     pc = cfg["ppo"]
-    logits, v = policy(pol, mb["x"], dt)
+    logits, v = policy(cfg, pol, mb["x"], dt)
     lsm = jax.nn.log_softmax(logits)
     logp = jnp.take_along_axis(lsm, mb["a"][:, None], -1)[:, 0]
     ratio = jnp.exp(logp - mb["logp"])
