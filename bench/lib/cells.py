@@ -11,19 +11,24 @@ name. Everything is data or a module of its own under ``bench/``:
 - ``bench/reference/aip/<kind>.py``, ``bench/reference/policy/<kind>.py``:
   an AIP backbone or a policy network, as the configuration's
   ``aip.kind`` or ``policy.kind`` names it: its weights, its plain
-  reference and its operation and word counts;
+  reference (a policy's learner with it) and its operation and word
+  counts;
+- ``bench/lib/policies/<kind>.py``: a policy network as the program
+  trains and serves it;
 - ``bench/lib/domains/<domain>.py`` and ``bench/reference/<domain>.py``:
   a domain's simulators as the program builds them, and its plain
   reference.
 
-A new cell, configuration, mix, metric or AIP backbone is a new file and
-a new entry; no existing file changes. So a configuration with a new
-backbone brings its config, its mix, its limits, any metric readers it
-needs, and ``bench/reference/aip/<kind>.py``. A new policy's module is a
-new file too, but the program has one policy network, ``ppo``'s shared
-MLP, and takes no kind: until it builds another, and a ``benchmark`` PR
-passes the kind on in ``train.Program`` and ``serve.Server``, set-up stops
-there, naming ``policy.kind`` (``weights.check_policy``).
+A new cell, configuration, mix, metric, AIP backbone or policy network
+is a new file and a new entry; no existing file changes. So a
+configuration with a new backbone brings its config, its mix, its
+limits, any metric readers it needs, and ``bench/reference/aip/<kind>.py``;
+one with a new policy network (one per agent region, say) brings
+``bench/lib/policies/<kind>.py`` (``train_config``, ``program_init``,
+``server``) and ``bench/reference/policy/<kind>.py`` (``init``,
+``forward``, ``learn``, ``flops``, ``weight_words``). Set-up stops,
+naming ``policy.kind``, where the two sides' parameters differ
+(``weights.check_policy``).
 """
 from __future__ import annotations
 

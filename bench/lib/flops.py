@@ -42,6 +42,8 @@ def aip_weight_words(cfg: dict) -> int:
 
 
 def policy_weight_words(cfg: dict) -> int:
+    """Every parameter the policy has: with one network per agent, all
+    A copies."""
     return policy.module(cfg).weight_words(cfg)
 
 

@@ -48,21 +48,11 @@ class Server:
     """The policy server under test, with the benchmark's recording."""
 
     def __init__(self, cfg: dict, mix: dict, seed: int):
-        from repro.rl import ppo
-        from repro.serving import PolicyServer
+        from bench.lib import policies
+        pol = policies.module(cfg)
         self.cfg, self.mix = cfg, mix
-        pol = cfg["policy"]
-        pcfg = ppo.PPOConfig(
-            obs_dim=cfg["obs_dim"], n_actions=cfg["n_actions"],
-            frame_stack=pol["frame_stack"], hidden=pol["hidden"],
-            fast_gates=pol["fast_gates"])
-        # the server serves checkpoints of ppo's one policy network
-        weights.check_policy(cfg, lambda k: ppo.init_policy(pcfg, k))
         self.params = weights.make(cfg, seed)["policy"]
-        self.server = PolicyServer(
-            self.params, obs_dim=cfg["obs_dim"], n_actions=cfg["n_actions"],
-            frame_stack=cfg["policy"]["frame_stack"], slot=mix["slot"],
-            fast_gates=cfg["policy"]["fast_gates"], route="auto")
+        self.server = pol.server(cfg, mix, self.params)
         self.server.warmup()
         forward = self.server.forward_slot
         self.outs = []

@@ -30,17 +30,18 @@ class Program:
     then started from any seed. Every width the configuration states
     reaches the program: the grid and agent count the simulators are
     built at, the policy's hidden width and frame stack, and the AIP's
-    ``aip`` block less its weights' seed and head bias. What the program
-    cannot take is checked against it, naming the key: the frame stack
-    ``rl_train`` trains the domain at, and the parameters of the one
-    policy network it has against those ``policy.kind`` builds."""
+    ``aip`` block less its weights' seed and head bias. The policy and
+    its learner are ``policy.kind``'s (``bench/lib/policies/<kind>.py``).
+    What the program cannot take is checked against it, naming the key:
+    the frame stack ``rl_train`` trains the domain at, and the policy's
+    parameters against those of the reference's ``policy.kind``."""
 
     def __init__(self, cfg: dict, mix: dict, devices):
         from repro.core import influence
         from repro.launch.mesh import make_host_mesh
-        from repro.rl import ppo
         from repro.launch import rl_train
-        from bench.lib import domains
+        from bench.lib import domains, policies
+        pol = policies.module(cfg)
         self.cfg, self.mix = cfg, mix
         A, B, pc = cfg["n_agents"], mix["n_envs"], cfg["ppo"]
         if A < 2:
@@ -62,17 +63,8 @@ class Program:
         if bad:
             raise ValueError(f"{cfg['name']}: the program builds, and the "
                              f"configuration states, {bad}")
-        self.pcfg = ppo.PPOConfig(
-            obs_dim=spec.obs_dim, n_actions=spec.n_actions,
-            frame_stack=cfg["policy"]["frame_stack"],
-            hidden=cfg["policy"]["hidden"], n_envs=B,
-            rollout_len=pc["rollout_len"], episode_len=pc["episode_len"],
-            gamma=pc["gamma"], lam=pc["lam"], clip=pc["clip"],
-            entropy_coef=pc["entropy_coef"], value_coef=pc["value_coef"],
-            lr=pc["lr"], epochs=pc["epochs"],
-            n_minibatches=pc["n_minibatches"], n_agents=A,
-            fast_gates=cfg["policy"]["fast_gates"])
-        weights.check_policy(cfg, lambda k: ppo.init_policy(self.pcfg, k))
+        self.pcfg = pol.train_config(cfg, spec, B)
+        weights.check_policy(cfg, pol.program_init(self.pcfg))
         widths = {k: v for k, v in cfg["aip"].items()
                   if k not in ("weights_seed", "head_bias")}
         self.acfg = influence.AIPConfig(

@@ -50,10 +50,11 @@ def make(cfg: dict, seed: int) -> dict:
 
 def check_policy(cfg: dict, program_init) -> None:
     """Stop set-up, naming ``policy.kind``, where the parameters the
-    program's policy takes (``program_init(key)``) are not those that the
-    configuration's kind builds. The program has one policy network,
-    ``ppo``'s shared MLP, and takes no kind: a kind whose parameters
-    differ would run the MLP against another network's reference."""
+    program's policy takes (``program_init(key)``, from the kind's
+    ``bench/lib/policies/<kind>.py``) are not those that the kind's
+    reference (``bench/reference/policy/<kind>.py``) builds. A new policy
+    kind brings both sides as new files; where their parameters differ,
+    one network would run against another's reference."""
     import jax
     from bench.reference import policy
 

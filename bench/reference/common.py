@@ -1,10 +1,12 @@
 """Plain reference of one PPO iteration on a Distributed IALS: the policy
 acts in A local simulators per env copy, each with its own influence
 predictor (AIP), for T ticks; then GAE and clipped-PPO minibatch epochs
-with Adam. Written from the papers' description (Suau et al. 2022;
-Schulman et al. 2017) in straightforward ``jax.numpy``: one scan over
-ticks in (B, A, ...) layout, a sequential GAE, and no kernels, caches or
-program code. It imports nothing of the program.
+with Adam, by the learner of the configuration's ``policy.kind``
+(``bench/reference/policy/<kind>.py``). Written from the papers'
+description (Suau et al. 2022; Schulman et al. 2017) in straightforward
+``jax.numpy``: one scan over ticks in (B, A, ...) layout, a sequential
+GAE, and no kernels, caches or program code. It imports nothing of the
+program.
 
 ``dt`` is the compute dtype: float32 (at JAX's default matmul precision,
 the precision the configurations state) for the reference, bfloat16 for
@@ -15,7 +17,8 @@ reference meets the program on the same draws: an iteration key splits
 into (rollout, update); the rollout key into T tick keys, each into
 (action, env, reset); actions are Gumbel-argmax; env noise splits into
 (AIP bits, LS noise); reset states are drawn per tick and merged where an
-episode ends; each epoch permutes the flattened (T, B, A) samples.
+episode ends; the update key splits into one key an epoch (the pooled
+learner, ``pooled.py``, permutes the flattened (T, B, A) samples by it).
 """
 from __future__ import annotations
 
@@ -173,74 +176,16 @@ def gae(batch, v_last, gamma, lam):
     return adv, adv + v
 
 
-def ppo_loss(cfg, pol, mb, dt):
-    pc = cfg["ppo"]
-    logits, v = policy(cfg, pol, mb["x"], dt)
-    lsm = jax.nn.log_softmax(logits)
-    logp = jnp.take_along_axis(lsm, mb["a"][:, None], -1)[:, 0]
-    ratio = jnp.exp(logp - mb["logp"])
-    adv = (mb["adv"] - mb["adv"].mean()) / (mb["adv"].std() + 1e-8)
-    pg = -jnp.minimum(ratio * adv, jnp.clip(ratio, 1 - pc["clip"],
-                                            1 + pc["clip"]) * adv).mean()
-    v_loss = jnp.square(v - mb["ret"]).mean()
-    ent = -(jnp.exp(lsm) * lsm).sum(-1).mean()
-    return pg + pc["value_coef"] * v_loss - pc["entropy_coef"] * ent
-
-
 def adam_init(pol):
     z = jax.tree_util.tree_map(jnp.zeros_like, pol)
     return {"step": jnp.zeros((), jnp.int32), "mu": z, "nu": z}
 
 
-def adam(cfg, pol, opt, g):
-    """Adam with global-norm gradient clipping, no weight decay."""
-    pc = cfg["ppo"]
-    leaves = jax.tree_util.tree_leaves(g)
-    norm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in leaves))
-    g = jax.tree_util.tree_map(
-        lambda x: x * jnp.minimum(1.0, pc["clip_norm"] / jnp.maximum(norm, 1e-9)), g)
-    step = opt["step"] + 1
-    b1, b2 = pc["adam_b1"], pc["adam_b2"]
-    mu = jax.tree_util.tree_map(lambda m, x: b1 * m + (1 - b1) * x, opt["mu"], g)
-    nu = jax.tree_util.tree_map(lambda n, x: b2 * n + (1 - b2) * x * x, opt["nu"], g)
-    c1 = 1.0 - b1 ** step.astype(jnp.float32)
-    c2 = 1.0 - b2 ** step.astype(jnp.float32)
-    pol = jax.tree_util.tree_map(
-        lambda p, m, n: p - pc["lr"] * ((m / c1) / (jnp.sqrt(n / c2) + pc["adam_eps"])),
-        pol, mu, nu)
-    return pol, {"step": step, "mu": mu, "nu": nu}
-
-
 def learn(cfg, pol, opt, batch, v_last, key, dt):
-    """GAE, then ``epochs`` passes of ``n_minibatches`` clipped-PPO Adam
-    steps over a fresh permutation each -> (pol, opt, mean loss)."""
-    pc = cfg["ppo"]
-    adv, ret = gae(batch, v_last, pc["gamma"], pc["lam"])
-    total = batch["a"].size
-    flat = {"x": batch["x"].reshape(total, -1), "a": batch["a"].reshape(total),
-            "logp": batch["logp"].reshape(total),
-            "adv": adv.reshape(total), "ret": ret.reshape(total)}
-    n_mb = pc["n_minibatches"]
-    size = total // n_mb
-
-    def epoch(carry, k):
-        perm = jax.random.permutation(k, total)[:n_mb * size]
-        shuf = jax.tree_util.tree_map(
-            lambda v: v[perm].reshape((n_mb, size) + v.shape[1:]), flat)
-
-        def step(carry, mb):
-            pol, opt = carry
-            loss, g = jax.value_and_grad(
-                lambda p: ppo_loss(cfg, p, mb, dt))(pol)
-            pol, opt = adam(cfg, pol, opt, g)
-            return (pol, opt), loss
-
-        carry, losses = jax.lax.scan(step, carry, shuf)
-        return carry, losses
-
-    (pol, opt), losses = jax.lax.scan(
-        epoch, (pol, opt), jax.random.split(key, pc["epochs"]))
-    return pol, opt, losses.mean()
+    """The learner of the configuration's ``policy.kind``: GAE and PPO
+    epochs over the rollout's (T, B, A) batch -> (pol, opt, mean loss).
+    ``opt`` is ``adam_init``'s state, ``mu`` Adam's first moment."""
+    return policies.module(cfg).learn(cfg, pol, opt, batch, v_last, key, dt)
 
 
 def make_iteration(cfg, dt):

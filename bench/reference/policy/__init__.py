@@ -1,12 +1,20 @@
 """The policy networks, one module per ``policy.kind`` of a configuration.
 
 Each module holds everything the benchmark knows about its network:
-``init(cfg, key)``, the parameters in the program's layout;
-``forward(p, x, dt)`` -> (logits, value); ``flops(cfg)``, one forward's
-matmul operations; ``weight_words(cfg)``. Every configuration file states
-its kind; a policy block without one is the shared MLP only so that the
-hand-count tests' configurations, written before kinds were named, read
-as they did.
+
+- ``init(cfg, key)``: the parameters in the program's layout;
+- ``forward(p, x, dt)`` -> (logits, value);
+- ``learn(cfg, pol, opt, batch, v_last, key, dt)`` -> (pol, opt, mean
+  loss): the learner, over the rollout's (T, B, A) batch, from and to
+  ``common.adam_init``'s state (``pooled.learn``, where one network is
+  shared by every agent);
+- ``flops(cfg)``: one forward's matmul operations, for one agent's
+  sample;
+- ``weight_words(cfg)``: every parameter ``init`` makes, in 4-byte
+  words: with one network per agent, all A copies.
+
+Every configuration states its kind; the program's side of it is
+``bench/lib/policies/<kind>.py``.
 """
 from __future__ import annotations
 
@@ -14,7 +22,7 @@ from bench.lib.cells import find
 
 
 def kind(cfg: dict) -> str:
-    return cfg["policy"].get("kind", "mlp")
+    return cfg["policy"]["kind"]
 
 
 def module(cfg: dict):

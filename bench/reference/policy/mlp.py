@@ -1,11 +1,15 @@
 """The shared actor-critic MLP: the agent's frame stack, two rational-tanh
-layers of ``hidden``, then a head of ``n_actions`` logits and a value."""
+layers of ``hidden``, then a head of ``n_actions`` logits and a value.
+One network for every agent, learnt by the pooled learner."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from bench.reference import pooled
 from bench.reference.common import dense, tanh_r
+
+learn = pooled.learn
 
 
 def init(cfg, key):

@@ -8,7 +8,7 @@ from bench.lib import cells, device, flops
 def _tiny(kind):
     return {"obs_dim": 3, "dset_dim": 2, "n_influence": 1, "n_actions": 2,
             "n_agents": 2, "ls_state_words": 3, "ls_noise_words": 1,
-            "policy": {"hidden": 4, "frame_stack": 2},
+            "policy": {"kind": "mlp", "hidden": 4, "frame_stack": 2},
             "aip": {"kind": kind, "hidden": 5, "stack": 3},
             "ppo": {"epochs": 2}}
 

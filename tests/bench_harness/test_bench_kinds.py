@@ -48,6 +48,37 @@ def test_weights_bitwise_as_before(name):
         == PARENT_WEIGHTS[name]
 
 
+# sha256 as above over the reference's record of its three checked
+# iterations (losses, rewards, values, Adam's first moment after one, the
+# parameters after three) at ``_tiny_train``'s size, from the weights of
+# SEED, on the CPU at the commit before the learner moved out of
+# ``bench/reference/common.py`` into ``pooled.py``
+PARENT_LEARNER = {
+    ("traffic25_fnn.train_b256", "float32"):
+        "dc49519ebc64984c81762df75895f242883594a4e57d617e4bbfe0b0b22f348f",
+    ("traffic25_fnn.train_b256", "bfloat16"):
+        "51c279ea99c41c05e125795b05b9d58ae3136b1ffee1f7c33775dd8b28cf763f",
+    ("warehouse36_gru.train_b256", "float32"):
+        "40b3ebef9741c04de69b3a757d58987365ae1d83845f69e8ded1b5238ae5196d",
+    ("warehouse36_gru.train_b256", "bfloat16"):
+        "5ebd909bfa0ccd4b6f6d9f241c0511098cdfa76cc83b44d9ebd02e97d34f251a",
+}
+
+
+@pytest.mark.parametrize("name,dt", sorted(PARENT_LEARNER))
+def test_pooled_learner_bitwise_as_before(name, dt):
+    """The reference and its bf16 control, learnt by the mlp kind's pooled
+    learner through ``common.learn``, read the recorded digests to the
+    bit."""
+    cfg, mix = _tiny_train(name)
+    w = weights.make(cfg, SEED)
+    rec = train.reference_steps(cfg, mix, SEED, train.host(w["policy"]),
+                                w["aip"], jnp.dtype(dt))
+    got = {k: np.asarray(v, np.float64) if isinstance(v, list) else v
+           for k, v in rec.items() if k != "p0"}
+    assert _digest(got) == PARENT_LEARNER[(name, dt)]
+
+
 def _aip_uses(cfg):
     B, A = 3, cfg["n_agents"]
     d = jnp.zeros((B, A, cfg["dset_dim"]))
@@ -62,6 +93,8 @@ def _policy_uses(cfg):
     x = jnp.zeros((3, cfg["obs_dim"] * cfg["policy"]["frame_stack"]))
     return {"weights": lambda: weights.make(cfg, SEED),
             "forward": lambda: common.policy(cfg, None, x, jnp.float32),
+            "learn": lambda: common.learn(cfg, None, None, None, None, None,
+                                          jnp.float32),
             "flops": lambda: flops.policy_flops(cfg),
             "kernel_cost": lambda: flops.rollout_kernel_cost(cfg, 4, 2, 2)}
 
@@ -92,6 +125,8 @@ def _stand_in(block, cfg):
                                                       7.0))
     mod.forward = lambda p, x, dt: (jnp.full(x.shape[:-1] + (2,), 7.0),
                                     jnp.zeros(x.shape[:-1]))
+    mod.learn = lambda cfg_, pol, opt, batch, v_last, key, dt: (
+        pol, opt, jnp.float32(7.0))
     mod.flops = lambda cfg_: 7_000_000
     mod.state_words = lambda cfg_: 7_000
     mod.weight_words = lambda cfg_: 7_000_000
@@ -113,6 +148,8 @@ def test_a_new_kind_is_a_new_module(block, monkeypatch):
         elif use in ("zero", "step", "forward"):
             out = got if use == "zero" else got[1 if use == "step" else 0]
             assert float(out.reshape(-1)[0]) == 7.0
+        elif use == "learn":
+            assert float(got[2]) == 7.0
         elif use == "flops":
             assert got == 7_000_000
         else:
@@ -198,22 +235,112 @@ def test_one_agent_is_refused():
         train.Program(cfg, mix, jax.devices())
 
 
-@pytest.mark.parametrize("cell", ["traffic25_fnn.train_b256",
-                                  "traffic25_fnn.serve_r80"])
+POLICY_CELLS = ["traffic25_fnn.train_b256", "traffic25_fnn.serve_r80"]
+
+
+def _build(cell, cfg, mix):
+    if cell.endswith("serve_r80"):
+        return serve.Server(cfg, mix, SEED)
+    return train.Program(cfg, mix, jax.devices())
+
+
+@pytest.mark.parametrize("cell", POLICY_CELLS)
 def test_a_policy_kind_the_program_does_not_build_stops_set_up(
         cell, monkeypatch):
-    """The program has one policy network and takes no kind: a kind whose
-    parameters differ from it stops the training and the serving set-up,
-    naming ``policy.kind``, before any weights are made."""
+    """A kind with a reference but no program side stops the training and
+    the serving set-up, naming the kind and ``bench/lib/policies``."""
     cfg, mix = _tiny_train(cell)
     cfg["policy"]["kind"] = "stand_in"
     mod = _stand_in("policy", cfg)
     monkeypatch.setitem(sys.modules, mod.__name__, mod)
+    with pytest.raises(ValueError,
+                       match="'stand_in'.*bench/lib/policies"):
+        _build(cell, cfg, mix)
+
+
+def _policy_sides(calls):
+    """A policy kind that comes as two new modules, the program's and the
+    reference's: the shared MLP under another name, each function noting
+    its calls in ``calls``."""
+    from bench.lib.policies import mlp as program_mlp
+    from bench.reference import pooled
+    from bench.reference.policy import mlp as reference_mlp
+
+    def noted(name, fn):
+        def call(*args):
+            calls.append(name)
+            return fn(*args)
+        return call
+
+    program = types.ModuleType("bench.lib.policies.stand_in")
+    for name in ("train_config", "program_init", "server"):
+        setattr(program, name, noted(name, getattr(program_mlp, name)))
+    reference = types.ModuleType("bench.reference.policy.stand_in")
+    for name in ("init", "forward", "flops", "weight_words"):
+        setattr(reference, name, getattr(reference_mlp, name))
+    reference.learn = noted("learn", pooled.learn)
+    return program, reference
+
+
+@pytest.mark.parametrize("cell", POLICY_CELLS)
+def test_a_new_policy_kind_is_two_new_modules(cell, monkeypatch):
+    """A policy kind whose two sides are new modules, and no existing file
+    names it, is built by ``Program`` and ``Server``, learnt by its own
+    ``learn`` in the reference, and comes out correct."""
+    cfg, mix = _tiny_train(cell)
+    cfg["policy"]["kind"] = "stand_in"
+    calls = []
+    for mod in _policy_sides(calls):
+        monkeypatch.setitem(sys.modules, mod.__name__, mod)
+    if cell.endswith("serve_r80"):
+        mix["rate_rps"] = 2000.0
+        srv = _build(cell, cfg, mix)
+        tr = serve.trace_for(cfg, mix, SEED, 0.2)
+        res = srv.replay(tr)
+        idx = np.arange(len(tr["arrival"]))
+        acts, logits = srv.served_outputs(res["where"], idx)
+        ref = serve.reference_logits(cfg, srv.params, tr["frame"][idx],
+                                     jnp.float32)
+        checks = serve.readings(acts, logits, ref)
+        assert calls == ["server"]
+    else:
+        prog = _build(cell, cfg, mix)
+        keys = train.iteration_keys(SEED, 0, train.CHECKED)
+        _, got = train.checked_steps(prog, prog.start(SEED), keys)
+        ref = train.reference_steps(cfg, mix, SEED, got["p0"], prog.aip,
+                                    jnp.float32)
+        checks = train.readings(got, ref)
+        assert calls == ["train_config", "program_init", "learn"]
+    ok, judged = verdict.judge(checks, cells.limits(cell))
+    assert ok, judged
+
+
+@pytest.mark.parametrize("cell", POLICY_CELLS)
+def test_a_policy_kind_whose_sides_differ_stops_set_up(cell, monkeypatch):
+    """A program side whose parameters are not the reference's stops the
+    training and the serving set-up, naming ``policy.kind``."""
+    from bench.lib.policies import mlp
+    cfg, mix = _tiny_train(cell)
+    cfg["policy"]["kind"] = "stand_in"
+    reference = _stand_in("policy", cfg)
+    program = types.ModuleType("bench.lib.policies.stand_in")
+    program.__dict__.update(train_config=mlp.train_config,
+                            program_init=mlp.program_init,
+                            server=mlp.server)
+    for mod in (reference, program):
+        monkeypatch.setitem(sys.modules, mod.__name__, mod)
     with pytest.raises(ValueError, match="policy.kind 'stand_in'"):
-        if cell.endswith("serve_r80"):
-            serve.Server(cfg, mix, SEED)
-        else:
-            train.Program(cfg, mix, jax.devices())
+        _build(cell, cfg, mix)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_WEIGHTS))
+def test_policy_weight_words_count_every_parameter(name):
+    """``rollout_kernel_cost``'s weights term reads every parameter the
+    kind's ``init`` makes, however many networks it holds."""
+    cfg = cells.config(name)
+    tree = jax.eval_shape(lambda: weights.make(cfg, SEED)["policy"])
+    assert flops.policy_weight_words(cfg) == sum(
+        x.size for x in jax.tree_util.tree_leaves(tree))
 
 
 def test_unknown_domain_raises():
